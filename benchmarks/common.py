@@ -117,10 +117,13 @@ def _key(ds, job, policy, la, b, n_runs, refit, backend, timeout, bucket):
     # pre-timeout-era files (no spend_trajectory/n_censored); v3 fences
     # off pre-geometry-bucket files — PR 5 changed the bootstrap-weight
     # derivation (trees.bootstrap_weights: padding-invariant per-point
-    # fold_in draws), which shifts every simulated outcome.
+    # fold_in draws), which shifts every simulated outcome; v4 fences off
+    # files from before the tree fit's node sums moved to the pinned fold
+    # (a backend-ordered matvec had split the lane-batched harness from
+    # the sequential oracle on some runs), which moves those runs.
     to = "__to" if timeout else ""
     return (f"{ds}__{job}__{policy}{la}__b{b}__r{n_runs}__{refit}"
-            f"__{_backend_key(backend, bucket)}{to}__v3")
+            f"__{_backend_key(backend, bucket)}{to}__v4")
 
 
 def run_policy(ds_name, job, policy, la, *, b=3.0, n_runs=20,

@@ -80,8 +80,11 @@ def main(argv=None):
     elif args.stream:
         common.DEFAULT_BACKEND = "stream"
     common.DEFAULT_SCHEDULER = args.scheduler
+    from repro import compile_cache
+    compile_cache.enable()
     n_runs = 5 if args.quick else args.runs
     only = args.only.split(",") if args.only else list(SECTIONS)
+    failed = []
     for name in only:
         t0 = time.time()
         print(f"== {name} ==", flush=True)
@@ -91,8 +94,12 @@ def main(argv=None):
             import traceback
             traceback.print_exc()
             print(f"bench,{name},ERROR,{type(e).__name__}", flush=True)
+            failed.append(name)
         print(f"bench,{name},seconds,{time.time() - t0:.1f}", flush=True)
+    if failed:
+        print(f"bench: sections failed: {','.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

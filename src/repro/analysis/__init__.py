@@ -5,7 +5,7 @@ Two layers mechanically enforce the ROADMAP's standing determinism contract
 bit) instead of leaving it to convention:
 
 * ``jaxpr_audit`` + ``rules`` — trace a program with ``jax.make_jaxpr`` and
-  walk the ClosedJaxpr (recursing into ``pjit``/``while``/``scan``/``cond``/
+  walk the ClosedJaxpr (recursing into ``jit``/``while``/``scan``/``cond``/
   ``pallas_call`` sub-jaxprs) with value-level taint propagation, enforcing
   the rules keyed to the repo's real historical failure modes:
 

@@ -138,7 +138,7 @@ def fixtures() -> list[Fixture]:
 def audit_fixture(fx: Fixture) -> list[Finding]:
     fn, args, rules = fx.build()
     if fx.x64:
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             return audit(fn, args, rules, program=fx.name)
     return audit(fn, args, rules, program=fx.name)
 

@@ -2,7 +2,7 @@
 
 ``audit(fn, example_args, rules)`` traces ``fn`` with ``jax.make_jaxpr`` and
 walks the resulting ClosedJaxpr — recursing into every sub-jaxpr a primitive
-carries (``pjit``, ``while``, ``scan``, ``cond``, ``custom_jvp``/``vjp``,
+carries (``jit``, ``while``, ``scan``, ``cond``, ``custom_jvp``/``vjp``,
 ``pallas_call``, remat) — while propagating value-level *labels* that the
 rules in ``analysis/rules.py`` consume.  The walker is rule-agnostic: it
 computes the label environment; rules are sink checkers over (eqn, labels).
@@ -27,9 +27,9 @@ carries a polarity label:
 
 Two auxiliary flags ride along: ``QUANT`` (value passed through the
 ``quantize_scores`` bit pattern — ``bitcast→add→and→bitcast``) and
-``SELIDX`` (index produced by an argmax over masked scores, so provably a
-non-padding index; ``iota == SELIDX`` one-hot compares therefore yield
-MASK, which is how the episode bodies' scatter masks stay clean through the
+``SELIDX`` (index produced by an argmax over masked scores, or by a min
+over ``where(pred, iota, fill)``, so provably a non-padding index;
+``iota == SELIDX`` one-hot compares therefore yield MASK, which is how the episode bodies' scatter masks stay clean through the
 ``while`` fixpoint).
 
 Loops are handled by iterating the body's transfer function until the carry
@@ -44,7 +44,7 @@ from typing import Any, Callable, Iterable
 
 import jax
 import numpy as np
-from jax import core as jcore
+from jax.extend import core as jcore
 
 __all__ = ["Finding", "Labels", "Rule", "audit", "audit_jaxpr",
            "program_signature", "signature"]
@@ -60,7 +60,7 @@ class Finding:
     rule: str                   # rule id, e.g. "R1"
     primitive: str              # offending primitive name
     message: str                # human-readable explanation
-    path: tuple[str, ...] = ()  # sub-jaxpr context, e.g. ("pjit:f", "while:body")
+    path: tuple[str, ...] = ()  # sub-jaxpr context, e.g. ("jit:jaxpr", "while:body")
     program: str = ""           # registry program name (filled by audit_all)
 
     def __str__(self):
@@ -82,6 +82,7 @@ class Labels:
     quant: bool = False
     selidx: bool = False
     iota_axes: tuple[int, ...] = ()   # axes this value is an iota over
+    splat: bool = False               # one scalar broadcast to every lane
 
     @property
     def cleanish(self) -> bool:
@@ -101,7 +102,8 @@ def _join(a: Labels, b: Labels) -> Labels:
         pol = DIRTY
     return Labels(pol=pol, quant=a.quant and b.quant,
                   selidx=a.selidx and b.selidx,
-                  iota_axes=tuple(set(a.iota_axes) & set(b.iota_axes)))
+                  iota_axes=tuple(set(a.iota_axes) & set(b.iota_axes)),
+                  splat=a.splat and b.splat)
 
 
 # --------------------------------------------------------------------------- #
@@ -202,6 +204,9 @@ class _Auditor:
                 lab = dataclasses.replace(
                     lab, iota_axes=tuple(dims[a] for a in lab.iota_axes
                                          if a < len(dims)))
+            if prim == "broadcast_in_dim":
+                lab = dataclasses.replace(
+                    lab, splat=not getattr(eqn.invars[0].aval, "shape", ()))
             return [lab]
         if prim in _GATHER:
             return [dataclasses.replace(ins[0], iota_axes=())]
@@ -249,7 +254,12 @@ class _Auditor:
             pol = CLEAN if ok else DIRTY
             if pol == CLEAN and all(c.pol == MASK for c in cases):
                 pol = MASK                # merging two masks stays a mask
-            return [Labels(pol=pol, quant=any(c.quant for c in cases))]
+            # where(pred, iota, fill): an iota with some lanes filled by a
+            # scalar, whose min is the lowest lane where pred holds.
+            iotas = {c.iota_axes for c in cases if not c.splat}
+            iota_axes = iotas.pop() if len(iotas) == 1 else ()
+            return [Labels(pol=pol, quant=any(c.quant for c in cases),
+                           iota_axes=iota_axes)]
         if prim in _ELEMENTWISE_CMP:
             # iota(m) == selection-index: a one-hot of a provably non-padding
             # index — False on every padding lane.
@@ -262,6 +272,11 @@ class _Auditor:
             src = ins[0]
             return [Labels(pol=DIRTY,
                            selidx=bool(src.quant or src.cleanish))]
+        if prim == "reduce_min" and ins[0].iota_axes and set(
+                eqn.params["axes"]) <= set(ins[0].iota_axes):
+            # min over where(score == top, iota, fill): the lowest-index
+            # argmax, a selection index like argmax's own.
+            return [Labels(pol=DIRTY, selidx=True)]
         if prim in _REDUCE or prim == "dot_general":
             return [_DIRTY for _ in eqn.outvars]
         if prim == "concatenate":
@@ -346,7 +361,7 @@ class _Auditor:
                 return _env.get(v, _DIRTY)
 
             subs = _subjaxprs(eqn)
-            if subs and prim in ("pjit", "closed_call", "core_call",
+            if subs and prim in ("jit", "closed_call", "core_call",
                                  "custom_jvp_call", "custom_vjp_call",
                                  "remat", "checkpoint", "custom_vmap_call"):
                 tag, sub = subs[0]

@@ -1,13 +1,11 @@
-"""Version-compatibility shims for the pinned toolchain.
+"""The one home of jax APIs whose spelling has drifted across releases.
 
-The repo targets the modern ``jax.tree`` namespace, but
-``jax.tree.leaves_with_path`` only landed after jax 0.4.37 (the pinned
-version here); the underlying implementation has lived in
-``jax.tree_util.tree_leaves_with_path`` since 0.4.6.  Route every
-*_with_path use through this module so a single site owns the fallback.
+Every ``*_with_path`` tree walk, ``shard_map`` and ``cost_analysis`` use in
+``src/`` goes through this module (the ``compat-drift`` lint rule in
+``repro.analysis.ast_lint`` enforces it), so the next drift is fixed here
+once.
 
-Supported floor: jax >= 0.4.26 (first release with the ``jax.tree``
-namespace used everywhere else in the codebase).
+Supported floor: jax 0.9.0, the installed release.
 """
 
 from __future__ import annotations
@@ -21,31 +19,16 @@ __all__ = ["tree_leaves_with_path", "shard_map", "cost_analysis_dict"]
 
 def tree_leaves_with_path(tree: Any,
                           is_leaf: Callable[[Any], bool] | None = None):
-    """``jax.tree.leaves_with_path`` with a ``jax.tree_util`` fallback.
-
-    Returns a list of ``(key_path, leaf)`` pairs.
-    """
-    fn = getattr(jax.tree, "leaves_with_path", None)
-    if fn is None:
-        fn = jax.tree_util.tree_leaves_with_path
-    return fn(tree, is_leaf=is_leaf)
+    """``jax.tree.leaves_with_path``: a list of ``(key_path, leaf)``."""
+    return jax.tree.leaves_with_path(tree, is_leaf=is_leaf)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-    """``jax.shard_map`` with the 0.4.x ``jax.experimental`` fallback."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+    """``jax.shard_map``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as one flat dict.
-
-    jax 0.4.x returns a list with one dict per program; newer releases
-    return the dict directly.  Missing/None analyses become ``{}``.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca) if ca else {}
+    """``Compiled.cost_analysis()`` as a dict (``{}`` when missing)."""
+    return dict(compiled.cost_analysis() or {})

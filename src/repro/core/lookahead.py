@@ -146,6 +146,23 @@ def _fit_root(key, y, obs_mask, cens, points, left, thresholds, floor,
     return params, assign, preds, mu, sigma
 
 
+# Speculative states fit per block of the state axis.  A level holds up to
+# M·k_gh^la states (3456 per lane for a 384-point space at la=2), each
+# fitting n_trees trees over all M points; fitting them all in one vmap
+# would hold every state's split statistics in HBM at once.  Blocking
+# bounds that to this many states per lane, whatever the space or depth.
+_FIT_BLOCK_STATES = 128
+
+
+def _map_states(fn, keys, y_b, m_b):
+    """``vmap(fn)`` over the state axis, evaluated in blocks of
+    ``_FIT_BLOCK_STATES`` (``lax.map``'s ``batch_size``: a scan over full
+    blocks plus one vmap over the remainder; at most one block is plain
+    vmap).  Every state runs the same per-state program either way."""
+    return jax.lax.map(lambda a: fn(*a), (keys, y_b, m_b),
+                       batch_size=_FIT_BLOCK_STATES)
+
+
 def _fit_batch_exact(key, y_b, m_b, cens_b, points, left, thresholds, floor,
                      s: Settings):
     """y_b, m_b[, cens_b]: [S, M] -> mu, sigma: [S, M].
@@ -167,7 +184,7 @@ def _fit_batch_exact(key, y_b, m_b, cens_b, points, left, thresholds, floor,
         preds = jnp.take_along_axis(p.leaf, a, axis=1)
         return trees.forest_mu_sigma(preds, floor)
 
-    mu, sigma = jax.vmap(one)(keys, y_b, m_b)
+    mu, sigma = _map_states(one, keys, y_b, m_b)
     if cens_b is not None:
         mu, sigma = acq.censored_adjust(mu, sigma, y_b, cens_b,
                                         s.cens_sigma_rel)
@@ -190,7 +207,7 @@ def _fit_batch_params(key, y_b, m_b, points, left, thresholds, s: Settings):
                                 n_trees=s.n_trees, depth=s.depth)
         return p
 
-    return jax.vmap(one)(keys, y_b, m_b)
+    return _map_states(one, keys, y_b, m_b)
 
 
 def _fit_batch_frozen(root_assign, root_preds, boot_w, sel_b, c_b, floor):

@@ -47,8 +47,8 @@ __all__ = [
 _EPS = 1e-12
 
 
-def _pinned_sum0(x: jax.Array) -> jax.Array:
-    """Sum over axis 0 in a fixed balanced pairwise order.
+def _pinned_sum0(x: jax.Array, axis: int = 0) -> jax.Array:
+    """Sum over ``axis`` (default 0) in a fixed balanced pairwise order.
 
     ``jnp.sum`` / ``@`` leave the accumulation order (and FMA formation) to
     the backend, which re-decides both per compilation context — the same
@@ -56,19 +56,26 @@ def _pinned_sum0(x: jax.Array) -> jax.Array:
     selector vs the fused Pallas program.  Zero-padding to a power of two and
     repeatedly adding the two halves pins one association that every context
     lowers identically (and stays vectorization-friendly: each step is a
-    single elementwise add of contiguous halves).
+    single elementwise add of contiguous halves).  The association depends
+    only on the folded axis' length, never on which axis it is, so a caller
+    may fold the minor axis (keeping a long axis lane-dense on TPU) and get
+    the same bits as folding it leading.
     """
-    m = x.shape[0]
+    axis = axis % x.ndim
+    m = x.shape[axis]
     size = 1
     while size < m:
         size *= 2
     if size != m:
-        x = jnp.concatenate(
-            [x, jnp.zeros((size - m,) + x.shape[1:], x.dtype)], axis=0)
-    while x.shape[0] > 1:
-        half = x.shape[0] // 2
-        x = x[:half] + x[half:]
-    return x[0]
+        pad = [(0, 0)] * x.ndim
+        pad[axis] = (0, size - m)
+        x = jnp.pad(x, pad)
+    while x.shape[axis] > 1:
+        half = x.shape[axis] // 2
+        x = (jax.lax.slice_in_dim(x, 0, half, axis=axis)
+             + jax.lax.slice_in_dim(x, half, 2 * half, axis=axis))
+    return jnp.squeeze(x, axis)
+
 
 # Fixed iteration count of the Knuth Poisson sampler below.  P(Poisson(1)
 # >= 24) ~ 1e-24: the truncation is unobservable, and a static bound keeps
@@ -129,18 +136,21 @@ def _fit_one_tree(y: jax.Array, w: jax.Array, points: jax.Array,
     assign = jnp.zeros((m,), dtype=jnp.int32)          # node pos at current lvl
     # Integer-valued weight sums (Poisson counts, well under 2^24) are exact
     # float32 in any order and need no pinning; the w·y sums are not, and
-    # leaf means feed the decision path, so they go through the fenced
-    # fixed-order fold (w·y fenced so the fold's first add cannot FMA it).
-    sw0 = jnp.sum(w)
+    # leaf means and split gains feed the decision path, so they go through
+    # the fenced fixed-order fold (w·y fenced so the fold's first add cannot
+    # FMA it).  Each level's per-node sums (sw_n, swy_n) are the previous
+    # level's per-child sums: one pinned fold per level serves both, and no
+    # backend-ordered w·y contraction reaches a node total (a matvec here
+    # split the lane-batched harness from the one-run oracle on the CPU).
+    sw_n = jnp.sum(w)[None]
     wy = _no_contract(w * y)
-    val = jnp.full((1,), _pinned_sum0(wy) / jnp.maximum(sw0, _EPS))
+    swy_n = _pinned_sum0(wy)[None]
+    val = swy_n / jnp.maximum(sw_n, _EPS)
 
     feat_lvls, thr_lvls = [], []
     for lvl in range(depth):
         n = 2 ** lvl
         onehot = (assign[:, None] == jnp.arange(n)[None, :]).astype(jnp.float32)
-        sw_n = onehot.T @ w                              # [n]
-        swy_n = onehot.T @ wy
         # Left-branch stats per (node, feature, threshold).  Contract the M
         # dimension as one [n, M] @ [M, F*T] matmul per statistic: this keeps
         # intermediates at O(n·F·T) instead of the naive einsum's O(M·F·T)
@@ -148,7 +158,12 @@ def _fit_one_tree(y: jax.Array, w: jax.Array, points: jax.Array,
         # speculative states affordable (and MXU-friendly on TPU).
         left_flat = left.reshape(m, f_dims * t_dims)
         stats = jnp.stack([w, wy], axis=0)               # [2, M]
-        node_stats = (onehot.T[None, :, :] * stats[:, None, :]) @ left_flat
+        # Full f32 products: a TPU's default precision would round w·y to
+        # bfloat16 first (~2^-9 relative), far above the noise floor and
+        # score grid the split argmax relies on.  A no-op on the CPU.
+        node_stats = jnp.matmul(onehot.T[None, :, :] * stats[:, None, :],
+                                left_flat,
+                                precision=jax.lax.Precision.HIGHEST)
         sl_w, sl_wy = (node_stats.reshape(2, n, f_dims, t_dims)[i]
                        for i in range(2))
         sr_w = sw_n[:, None, None] - sl_w
@@ -180,36 +195,44 @@ def _fit_one_tree(y: jax.Array, w: jax.Array, points: jax.Array,
         # lowest index identically in every compilation context.
         flat = _quantize_scores(gain).reshape(n, f_dims * t_dims)
         best = jnp.argmax(flat, axis=1)
-        best_gain = jnp.take_along_axis(flat, best[:, None], axis=1)[:, 0]
+        best_gain = jnp.max(flat, axis=1)                # == flat[best]
         f_sel = (best // t_dims).astype(jnp.int32)
-        # thresholds are shared columns of `left`; recover the value lazily at
-        # traversal time via the same (f, t) pair — store threshold *value*:
-        t_sel = best % t_dims
         degenerate = ~jnp.isfinite(best_gain)
         f_sel = jnp.where(degenerate, 0, f_sel)
 
         feat_pad = jnp.zeros((width,), jnp.int32).at[:n].set(f_sel)
         feat_lvls.append(feat_pad)
-        # Route points: go right iff NOT left of threshold.
-        goes_left = left[jnp.arange(m), f_sel[assign], t_sel[assign]] > 0.5
-        goes_left = goes_left | degenerate[assign]
+        # Route points: go right iff NOT left of threshold.  Each node's
+        # split column of ``left`` comes out of an exact 0/1 one-hot
+        # contraction and each point reads its own node's row: a per-point
+        # gather into the [M, F, T] table is what a TPU lowers worst (it
+        # touches the whole table per point).
+        split_oh = (jnp.arange(f_dims * t_dims)[None, :]
+                    == best[:, None]).astype(jnp.float32)
+        node_left = ((split_oh @ left_flat.T > 0.5)
+                     | degenerate[:, None])                # [n, M]
+        goes_left = jnp.any(node_left & (onehot.T > 0.5), axis=0)
         assign = 2 * assign + (~goes_left).astype(jnp.int32)
         # Child means with parent fallback.
         n2 = 2 * n
-        oh2 = (assign[:, None] == jnp.arange(n2)[None, :]).astype(jnp.float32)
-        sw2 = oh2.T @ w
+        oh2 = (jnp.arange(n2)[:, None] == assign[None, :]).astype(jnp.float32)
+        sw2 = oh2 @ w                                    # [2n]
         # One-hot masking (0/1 products are exact) + pinned fold keeps the
         # child means bit-stable across compilation contexts; the matmul
-        # above may stay — its integer sums are exact in any order.
-        swy2_ = _pinned_sum0(oh2 * wy[:, None])
+        # above may stay — its integer sums are exact in any order.  The
+        # product is laid out [2n, M] and folded over its minor M axis: a
+        # minor axis of 2n <= 16 would be padded to the TPU's 128-lane tile,
+        # an 8x-16x blow-up across thousands of speculative fits.
+        swy2_ = _pinned_sum0(oh2 * wy[None, :], axis=1)
         parent = jnp.repeat(val, 2)
         val = jnp.where(sw2 > min_weight - 1e-9,
                         swy2_ / jnp.maximum(sw2, _EPS), parent)
+        sw_n, swy_n = sw2, swy2_
         # Store threshold as an actual value for standalone prediction. We
         # need the numeric threshold: gather from the shared grid is not
         # available here (left is boolean), so thresholds are passed in
         # alongside; see fit_forest which closes over them.
-        thr_lvls.append((f_sel, t_sel, degenerate, n))
+        thr_lvls.append((best, degenerate, n))
 
     return assign, val, feat_lvls, thr_lvls
 
@@ -245,8 +268,12 @@ def fit_forest(key: jax.Array, y: jax.Array, obs_mask: jax.Array,
             y, wi, points, left, depth=depth, min_weight=min_weight)
         feat = jnp.stack(feat_lvls) if depth > 0 else jnp.zeros((0, width), jnp.int32)
         thr_rows = []
-        for (f_sel, t_sel, degenerate, n) in thr_meta:
-            tv = thresholds[f_sel, t_sel]
+        thr_flat = thresholds.reshape(-1)
+        for (best, degenerate, n) in thr_meta:
+            # The split's threshold as an exact one-hot select (a max over
+            # one value and -infs): a TPU gathers per node very slowly.
+            hit = jnp.arange(thr_flat.shape[0])[None, :] == best[:, None]
+            tv = jnp.max(jnp.where(hit, thr_flat[None, :], -jnp.inf), axis=1)
             tv = jnp.where(degenerate, jnp.inf, tv)
             thr_rows.append(jnp.full((width,), jnp.inf).at[:n].set(tv))
         thr = jnp.stack(thr_rows) if depth > 0 else jnp.zeros((0, width), jnp.float32)

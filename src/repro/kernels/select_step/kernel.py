@@ -6,19 +6,25 @@ candidate configs (``tree_predict``), turn the posterior into constrained
 EI + budget filter + Gauss-Hermite cost nodes (``gh_ei``), and argmax the
 quantized masked scores.  Unfused, each stage round-trips its [S, M]
 intermediates through HBM.  This kernel keeps one state block's whole
-[bs, M] sweep in VMEM: one-hot-matmul ensemble descent (the
-``tree_predict`` idiom), the *exact* acquisition expressions from
-``repro.core.acquisition`` (called verbatim, so the primitive sequence is
-the unfused selector's), and the in-kernel argmax over
-``quantize_scores``-rounded integers.
+[bs, M] sweep in VMEM: ensemble descent by exact selects on [bs, M] tiles,
+the *exact* acquisition expressions from ``repro.core.acquisition``
+(called verbatim, so the primitive sequence is the unfused selector's),
+and the in-kernel argmax over ``quantize_scores``-rounded integers.
+
+TPU layout: every operand is a rank-2 block whose minor axis is either
+long and lane-dense (M, or the flattened per-state forest params) or the
+whole array axis; per-state scalars travel as one [S, 4] block and
+per-state results as [S, 1] columns.  Rank-1 blocks off the 128-lane tile,
+batched SMEM scalars (the R-lane selector ``vmap``s this call) and 3-D
+in-kernel reshapes do not lower on a TPU.
 
 Bit-exactness contract (pinned by tests/test_kernels.py): with the forest
 params of ``trees.fit_forest``, the in-kernel traversal reproduces the
 fit-side leaf ``assign`` exactly — ``right = x > thr`` with the stored
 threshold value is the complement of the fit's ``left`` table routing, and
-degenerate splits store ``thr = +inf`` (everything left) in both.  One-hot
-sums gather single finite values (exact), and mean/std/erf are evaluated
-by the same jnp calls the unfused program traces.
+degenerate splits store ``thr = +inf`` (everything left) in both.  Selects
+move single finite values (exact), and mean/std/erf are evaluated by the
+same jnp calls the unfused program traces.
 
 Geometry-bucket padding lanes arrive via ``valid`` and are masked out of
 the untested set and the incumbent fallback before their ``-inf`` scores
@@ -32,7 +38,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import acquisition as acq
 from repro.core import trees
@@ -53,8 +58,6 @@ def _kernel(*refs, n_trees, depth, width, n_leaves, n_feat, m_dim, conf,
     leaf_ref = next(it)
     y_ref = next(it)
     obs_ref = next(it)
-    beta_ref = next(it)
-    bf_ref = next(it)
     cens_ref = next(it) if has_cens else None
     points_ref = next(it)
     u_ref = next(it)
@@ -62,52 +65,51 @@ def _kernel(*refs, n_trees, depth, width, n_leaves, n_feat, m_dim, conf,
     xi_ref = next(it) if want_nodes else None
     outs = list(it)
 
-    t_max = scal_ref[0]
-    floor = scal_ref[1]
-    x = points_ref[...]                                  # [M, F]
+    beta = scal_ref[:, 0]                                # [bs]
+    bf = scal_ref[:, 1]                                  # [bs]
+    t_max = scal_ref[:, 2:3]                             # [bs, 1]
+    floor = scal_ref[:, 3:4]                             # [bs, 1]
     y = y_ref[...]                                       # [bs, M]
     obs = obs_ref[...]                                   # [bs, M] bool
-    beta = beta_ref[...]                                 # [bs]
-    bf = bf_ref[...]                                     # [bs]
-    u = u_ref[...]                                       # [M]
+    u = u_ref[0]                                         # [M]
     bs = y.shape[0]
+    # Feature rows of the space, lane-dense over M: x_rows[f] is [1, M].
+    x_rows = [points_ref[f:f + 1, :] for f in range(n_feat)]
 
-    # Ensemble descent, batched over the state block: per (tree, level) a
-    # one-hot feature matmul yields every node's candidate value for all M
-    # points at once; the current position selects its column (VPU select)
-    # and doubles per level.  ``right = val > thr`` replays the fit-side
-    # left-table routing exactly (+inf threshold => degenerate => left).
+    # Ensemble descent, batched over the state block, as exact selects on
+    # [bs, M] tiles: per (tree, level) the node position picks its split's
+    # feature and threshold (a where-chain over the 2**lvl reachable
+    # nodes), the feature picks the point's coordinate, and ``right = val >
+    # thr`` replays the fit-side left-table routing exactly (+inf threshold
+    # => degenerate => left).  Selects move values without arithmetic, so
+    # the descent is bit-exact on every backend.
+    def pick(idx, col, n):
+        out = col(0)
+        for j in range(1, n):
+            out = jnp.where(idx == j, col(j), out)
+        return out
+
     preds = []
     for b in range(n_trees):
         pos = jnp.zeros((bs, m_dim), jnp.int32)
         for lvl in range(depth):
-            feat_l = feat_ref[:, b, lvl]                 # [bs, W] int32
-            thr_l = thr_ref[:, b, lvl]                   # [bs, W] f32
-            onehot = (jax.lax.broadcasted_iota(
-                jnp.int32, (bs, n_feat, width), 1)
-                == feat_l[:, None, :]).astype(jnp.float32)
-            vals = jax.lax.dot_general(
-                x, onehot, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)      # [M, bs, W]
-            vals = vals.transpose(1, 0, 2)               # [bs, M, W]
-            sel_w = (jax.lax.broadcasted_iota(
-                jnp.int32, (bs, m_dim, width), 2) == pos[:, :, None])
-            val = jnp.sum(jnp.where(sel_w, vals, 0.0), axis=2)
-            th = jnp.sum(jnp.where(sel_w, thr_l[:, None, :], 0.0), axis=2)
+            base = (b * depth + lvl) * width
+            node_col = lambda ref: lambda j: ref[:, base + j:base + j + 1]
+            f_at = pick(pos, node_col(feat_ref), 2 ** lvl)   # [bs, M]
+            th = pick(pos, node_col(thr_ref), 2 ** lvl)
+            val = pick(f_at, lambda f: x_rows[f], n_feat)
             right = val > th
             pos = 2 * pos + right.astype(jnp.int32)
-        leaf_b = leaf_ref[:, b]                          # [bs, L]
-        lsel = (jax.lax.broadcasted_iota(
-            jnp.int32, (bs, m_dim, n_leaves), 2) == pos[:, :, None])
-        preds.append(jnp.sum(jnp.where(lsel, leaf_b[:, None, :], 0.0),
-                             axis=2))                    # [bs, M]
+        base = b * n_leaves
+        preds.append(pick(pos, lambda j: leaf_ref[:, base + j:base + j + 1],
+                          n_leaves))                     # [bs, M]
     preds = jnp.stack(preds)                             # [B, bs, M]
 
     mu, sigma = trees.forest_mu_sigma(preds, floor)
     if has_cens:
         mu, sigma = acq.censored_adjust(mu, sigma, y, cens_ref[...],
                                         cens_rel)
-    valid = valid_ref[...] if has_valid else None
+    valid = valid_ref[0] if has_valid else None
     ystar = acq.incumbent_fallback(bf, y, obs, sigma, valid)
     eic = acq.ei_constrained(mu, sigma, ystar[:, None], u[None, :], t_max)
     untested = ~obs
@@ -118,19 +120,28 @@ def _kernel(*refs, n_trees, depth, width, n_leaves, n_feat, m_dim, conf,
         cand = cand & acq.budget_ok(mu, sigma, beta[:, None], conf)
     raw = eic if score_mode == "eic" else eic / jnp.maximum(mu, _EPS)
     score = acq.quantize_scores(jnp.where(cand, raw, -jnp.inf))
-    sel = jnp.argmax(score, axis=1).astype(jnp.int32)
+    # Quantized scores tie exactly by design, and Mosaic's argmax breaks
+    # ties differently from XLA's lowest-index rule (it disagreed on 49 of
+    # 256 states on a v5e).  So the pick is read directly: the lowest index
+    # among the top scores.
+    iota = jax.lax.broadcasted_iota(jnp.int32, score.shape, 1)
+    top = jnp.max(score, axis=1, keepdims=True)
+    sel = jnp.min(jnp.where(score == top, iota, m_dim), axis=1)
     has_cand = jnp.any(cand, axis=1)
 
+    # Per-state outputs are [bs, 1] columns (a rank-1 block would have to
+    # be a multiple of the 128-lane tile on TPU); ``col`` lifts [bs] -> [bs, 1].
+    col = lambda a: a[:, None]
     if emit_full:
         (mu_ref, sig_ref, eic_ref, ystar_ref, cand_ref, sel_ref,
          has_ref) = outs[:7]
         mu_ref[...] = mu
         sig_ref[...] = sigma
         eic_ref[...] = eic
-        ystar_ref[...] = ystar
+        ystar_ref[...] = col(ystar)
         cand_ref[...] = cand
-        sel_ref[...] = sel
-        has_ref[...] = has_cand
+        sel_ref[...] = col(sel)
+        has_ref[...] = col(has_cand)
         if want_nodes:
             outs[7][...] = acq.gh_cost_nodes(mu, sigma, xi_ref[...])
         return
@@ -141,11 +152,11 @@ def _kernel(*refs, n_trees, depth, width, n_leaves, n_feat, m_dim, conf,
     mu_sel = take(mu)
     sig_sel = take(sigma)
     sel_ref, has_ref, eics_ref, mus_ref, sigs_ref = outs[:5]
-    sel_ref[...] = sel
-    has_ref[...] = has_cand
-    eics_ref[...] = eic_sel
-    mus_ref[...] = mu_sel
-    sigs_ref[...] = sig_sel
+    sel_ref[...] = col(sel)
+    has_ref[...] = col(has_cand)
+    eics_ref[...] = col(eic_sel)
+    mus_ref[...] = col(mu_sel)
+    sigs_ref[...] = col(sig_sel)
     if want_nodes:
         outs[5][...] = acq.gh_cost_nodes(mu_sel, sig_sel, xi_ref[...])
 
@@ -179,72 +190,64 @@ def select_step_call(feat, thr, leaf, y, obs, beta, bf, points, u, t_max,
     bs = min(bs, s_dim)
     pad = (-s_dim) % bs
     pad_s = lambda a: jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+    # Per-state scalars as one [S, 4] block (beta, y*, t_max, floor): a
+    # vmapped call batches every operand, and the TPU lowering takes no
+    # batched SMEM scalars nor rank-1 blocks off the 128-lane tile.
+    scal = jnp.stack(jnp.broadcast_arrays(
+        *(jnp.asarray(a, jnp.float32) for a in (beta, bf, t_max, floor))),
+        axis=1)
     if pad:
-        feat, thr, leaf, y, obs, beta, bf = map(
-            pad_s, (feat, thr, leaf, y, obs, beta, bf))
+        feat, thr, leaf, y, obs, scal = map(
+            pad_s, (feat, thr, leaf, y, obs, scal))
         if cens is not None:
             cens = pad_s(cens)
     sp = s_dim + pad
 
-    scal = jnp.stack([jnp.asarray(t_max, jnp.float32),
-                      jnp.asarray(floor, jnp.float32)])
     has_cens = cens is not None
     has_valid = valid is not None
 
-    operands = [scal, feat.astype(jnp.int32), thr.astype(jnp.float32),
-                leaf.astype(jnp.float32), y.astype(jnp.float32),
-                obs.astype(bool), beta.astype(jnp.float32),
-                bf.astype(jnp.float32)]
-    in_specs = [
-        pl.BlockSpec(memory_space=pltpu.SMEM),
-        pl.BlockSpec((bs, n_trees, depth, width), lambda i: (i, 0, 0, 0)),
-        pl.BlockSpec((bs, n_trees, depth, width), lambda i: (i, 0, 0, 0)),
-        pl.BlockSpec((bs, n_trees, n_leaves), lambda i: (i, 0, 0)),
-        pl.BlockSpec((bs, m_dim), lambda i: (i, 0)),
-        pl.BlockSpec((bs, m_dim), lambda i: (i, 0)),
-        pl.BlockSpec((bs,), lambda i: (i,)),
-        pl.BlockSpec((bs,), lambda i: (i,)),
-    ]
-    if has_cens:
-        operands.append(cens.astype(bool))
-        in_specs.append(pl.BlockSpec((bs, m_dim), lambda i: (i, 0)))
-    operands += [points.astype(jnp.float32), u.astype(jnp.float32)]
-    in_specs += [pl.BlockSpec((m_dim, n_feat), lambda i: (0, 0)),
-                 pl.BlockSpec((m_dim,), lambda i: (0,))]
-    if has_valid:
-        operands.append(valid.astype(bool))
-        in_specs.append(pl.BlockSpec((m_dim,), lambda i: (0,)))
-    if want_nodes:
-        k_gh = xi.shape[0]
-        operands.append(xi.astype(jnp.float32))
-        in_specs.append(pl.BlockSpec((k_gh,), lambda i: (0,)))
-
+    # Every operand is rank-2 and lane-dense over its minor axis where it
+    # has a long one: per-state forest params flattened to [S, B*D*W] /
+    # [S, B*L], the space transposed to [F, M], shared rows as [1, M] /
+    # [1, K].
+    row = lambda a: a.reshape(1, -1)
+    operands = [scal, feat.astype(jnp.int32).reshape(sp, -1),
+                thr.astype(jnp.float32).reshape(sp, -1),
+                leaf.astype(jnp.float32).reshape(sp, -1),
+                y.astype(jnp.float32), obs.astype(bool)]
     blk = lambda *tail: pl.BlockSpec((bs,) + tail,
                                      lambda i: (i,) + (0,) * len(tail))
+    whole = lambda *shape: pl.BlockSpec(shape, lambda i: (0,) * len(shape))
+    node_w = n_trees * depth * width
+    in_specs = [blk(4), blk(node_w), blk(node_w), blk(n_trees * n_leaves),
+                blk(m_dim), blk(m_dim)]
+    if has_cens:
+        operands.append(cens.astype(bool))
+        in_specs.append(blk(m_dim))
+    operands += [points.astype(jnp.float32).T, row(u.astype(jnp.float32))]
+    in_specs += [whole(n_feat, m_dim), whole(1, m_dim)]
+    if has_valid:
+        operands.append(row(valid.astype(bool)))
+        in_specs.append(whole(1, m_dim))
+    if want_nodes:
+        k_gh = xi.shape[0]
+        operands.append(row(xi.astype(jnp.float32)))
+        in_specs.append(whole(1, k_gh))
+
+    f32, i32, b1 = jnp.float32, jnp.int32, jnp.bool_
     if emit_full:
-        out_specs = [blk(m_dim), blk(m_dim), blk(m_dim), blk(),
-                     blk(m_dim), blk(), blk()]
-        out_shape = [jax.ShapeDtypeStruct((sp, m_dim), jnp.float32),
-                     jax.ShapeDtypeStruct((sp, m_dim), jnp.float32),
-                     jax.ShapeDtypeStruct((sp, m_dim), jnp.float32),
-                     jax.ShapeDtypeStruct((sp,), jnp.float32),
-                     jax.ShapeDtypeStruct((sp, m_dim), jnp.bool_),
-                     jax.ShapeDtypeStruct((sp,), jnp.int32),
-                     jax.ShapeDtypeStruct((sp,), jnp.bool_)]
+        shapes = [((m_dim,), f32), ((m_dim,), f32), ((m_dim,), f32),
+                  ((1,), f32), ((m_dim,), b1), ((1,), i32), ((1,), b1)]
         if want_nodes:
-            out_specs.append(blk(m_dim, k_gh))
-            out_shape.append(
-                jax.ShapeDtypeStruct((sp, m_dim, k_gh), jnp.float32))
+            shapes.append(((m_dim, k_gh), f32))
     else:
-        out_specs = [blk(), blk(), blk(), blk(), blk()]
-        out_shape = [jax.ShapeDtypeStruct((sp,), jnp.int32),
-                     jax.ShapeDtypeStruct((sp,), jnp.bool_),
-                     jax.ShapeDtypeStruct((sp,), jnp.float32),
-                     jax.ShapeDtypeStruct((sp,), jnp.float32),
-                     jax.ShapeDtypeStruct((sp,), jnp.float32)]
+        shapes = [((1,), i32), ((1,), b1), ((1,), f32), ((1,), f32),
+                  ((1,), f32)]
         if want_nodes:
-            out_specs.append(blk(k_gh))
-            out_shape.append(jax.ShapeDtypeStruct((sp, k_gh), jnp.float32))
+            shapes.append(((k_gh,), f32))
+    out_specs = [blk(*tail) for tail, _ in shapes]
+    out_shape = [jax.ShapeDtypeStruct((sp,) + tail, dt)
+                 for tail, dt in shapes]
 
     kernel = functools.partial(
         _kernel, n_trees=n_trees, depth=depth, width=width,
@@ -260,4 +263,6 @@ def select_step_call(feat, thr, leaf, y, obs, beta, bf, points, u, t_max,
         out_shape=out_shape,
         interpret=interpret,
     )(*operands)
-    return tuple(o[:s_dim] for o in outs)
+    # Drop the state padding and the per-state columns' unit lane axis.
+    return tuple(o[:s_dim, 0] if tail == (1,) else o[:s_dim]
+                 for o, (tail, _) in zip(outs, shapes))

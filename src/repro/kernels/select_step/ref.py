@@ -2,8 +2,10 @@
 
 Mirrors the unfused selector's expressions exactly: gather-based forest
 traversal (``trees.predict_forest``), the shared acquisition functions,
-``take_along_axis`` gathers at the argmax pick.  The Pallas kernel's
-one-hot-matmul formulation must match this bit for bit
+``take_along_axis`` gathers at the argmax pick.  The Pallas kernel
+evaluates the same expressions; its values can differ from this
+reference's in the last ulp, while its decisions and quantized
+diagnostics replay the unfused *selector* bit for bit
 (tests/test_kernels.py).
 """
 

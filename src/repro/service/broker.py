@@ -304,6 +304,10 @@ class StreamingTuner:
                             for _ in range(self.num_shards)]
         self._shard_metrics = [MetricsRecorder(self.config.lane_slots)
                                for _ in range(self.num_shards)]
+        # Per shard, the tickets staged into the running segment: out of
+        # the admission buffer but not (or not yet) seated in a slot —
+        # placement counts them (see _place_shard).  Guarded by _cond.
+        self._handed: list[list] = [[] for _ in range(self.num_shards)]
         self._rr = 0                         # round-robin placement cursor
         self._cond = threading.Condition()
         self._pump_lock = threading.RLock()
@@ -449,14 +453,21 @@ class StreamingTuner:
 
     def _place_shard(self, home: int | None = None) -> int:
         """Choose a ticket's home shard (``config.placement_policy``) over
-        the instantaneous loads ``backlog + seated`` of each shard.  Called
-        under ``self._cond`` at admission; the choice is sticky for the
-        ticket's life (resume re-queues to the home shard directly)."""
+        the instantaneous loads ``backlog + seated + staged-unseated`` of
+        each shard.  A staged ticket leaves the backlog before it reaches a
+        slot — for the whole segment when it waits in the device queue —
+        so it is counted until the pump that staged it ends.  Called under
+        ``self._cond`` at admission; the choice is sticky for the ticket's
+        life (resume re-queues to the home shard directly)."""
         n = self.num_shards
         if n == 1:
             return 0
-        loads = [len(self._admissions[d])
-                 + self._engines.shards[d].in_flight() for d in range(n)]
+        loads = []
+        for d in range(n):
+            seated = {id(t) for t in self._engines.shards[d]._slot_tickets
+                      if t is not None}
+            loads.append(len(self._admissions[d]) + len(seated)
+                         + sum(id(t) not in seated for t in self._handed[d]))
         d = placement.choose_shard(self.config.placement_policy, loads,
                                    home=home, rr=self._rr)
         self._rr += 1
@@ -563,9 +574,11 @@ class StreamingTuner:
                 for t in adm.purge_cancelled():
                     self._finish_cancel(t)
                 depth = len(adm)              # admitted, not yet staged
-                staged = adm.stage(
-                    eng.c_dim + self.config.lane_slots - eng.in_flight(),
-                    aging_rate=self.config.aging_rate)
+                with self._cond:              # atomic for _place_shard
+                    staged = adm.stage(
+                        eng.c_dim + self.config.lane_slots - eng.in_flight(),
+                        aging_rate=self.config.aging_rate)
+                    self._handed[d] = staged
                 for t in staged:
                     self.recorder.emit("stage", ticket=t.id,
                                        priority=t.priority, shard=d)
@@ -650,6 +663,8 @@ class StreamingTuner:
             with self._cond:
                 self._outstanding -= len(resolved_tickets)
                 self._unharvested.extend(resolved_tickets)
+                # Leftovers and preempted runs are back in their backlogs.
+                self._handed = [[] for _ in range(self.num_shards)]
                 self._cond.notify_all()
             if failure is not None:
                 raise failure

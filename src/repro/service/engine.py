@@ -236,6 +236,29 @@ class SegmentEngine:
             queue[f] = self._place(jnp.asarray(buf))
         return queue
 
+    def segment_args(self, staged: list = (), evict=None,
+                     low_water: int = 0, step_quota: int = 1) -> tuple:
+        """The positional arguments of this engine's next
+        ``_episode_segment`` dispatch (settings last): the resident carry
+        and tables plus ``staged`` as the device queue.  With the defaults
+        it is an empty segment's — what ``_episode_segment.lower(*args)``
+        needs to compile the engine's program ahead of time."""
+        evict = np.zeros(self.l_dim, bool) if evict is None else evict
+        if self._single:
+            job_ids = None
+        else:
+            job_ids = self._place(jnp.asarray(np.concatenate(
+                [self._slot_jids,
+                 np.array([t.jid for t in staged], np.int32),
+                 np.zeros(self.c_dim - len(staged), np.int32)])))
+        return (self._carry, self._queue_arrays(list(staged)),
+                np.int32(len(staged)), self._place(jnp.asarray(evict)),
+                np.int32(low_water), np.int32(step_quota), job_ids,
+                self._cost,
+                self._runtime if self.settings.timeout else None,
+                *self._space, self._valid, self._u, self._tmax,
+                self.settings)
+
     def run_segment(self, staged: list, evict_tickets: list,
                     low_water: int, step_quota: int
                     ) -> tuple[list, list, list, list, SegmentReport]:
@@ -293,30 +316,16 @@ class SegmentEngine:
 
         with phase_span(rec, "inject", segment=seg, profiler=prof,
                         shard=self.shard_id):
-            queue = self._queue_arrays(staged_q)
+            args = self.segment_args(staged_q, ev, low_water, step_quota)
             for j, t in enumerate(staged_q):
                 rec.emit("inject", ticket=t.id, segment=seg, row=j,
                          shard=self.shard_id)
-        if self._single:
-            job_ids = None
-        else:
-            job_ids = self._place(jnp.asarray(np.concatenate(
-                [self._slot_jids,
-                 np.array([t.jid for t in staged_q], np.int32),
-                 np.zeros(self.c_dim - len(staged_q), np.int32)])))
         # dispatch = host-side trace/compile + launch; device_block = the
         # wait for the device to finish.  Splitting them is what lets the
         # report tell compile stalls from slow segments.
         with phase_span(rec, "dispatch", segment=seg, profiler=prof,
                         compiles=True, shard=self.shard_id):
-            carry, report = _episode_segment(
-                self._carry, queue, np.int32(len(staged_q)),
-                self._place(jnp.asarray(ev)),
-                np.int32(low_water), np.int32(step_quota), job_ids,
-                self._cost,
-                self._runtime if self.settings.timeout else None,
-                *self._space, self._valid, self._u, self._tmax,
-                self.settings)
+            carry, report = _episode_segment(*args)
         with phase_span(rec, "device_block", segment=seg, profiler=prof,
                         shard=self.shard_id):
             carry, report = jax.block_until_ready((carry, report))

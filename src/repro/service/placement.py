@@ -7,10 +7,10 @@ ticket to exactly one shard, JetStream/MaxText-style (engine-per-device,
 one host broker).  This module is the host-side half of that routing:
 
 * :func:`choose_shard` — the placement policies.  ``least_backlog`` picks
-  the shard with the fewest unfinished tickets (backlog + in-flight),
-  lowest shard id breaking ties; ``round_robin`` rotates.  Both are pure
-  functions of host-side integers — placement can never consult device
-  state, so it can never perturb a traced program.
+  the shard with the fewest unfinished tickets (backlog + staged +
+  in-flight), lowest shard id breaking ties; ``round_robin`` rotates.
+  Both are pure functions of host-side integers — placement can never
+  consult device state, so it can never perturb a traced program.
 * **Sticky affinity** — a ticket that has ever been placed keeps its
   ``ticket.shard`` for life: cancel, preempt and resume are single-shard
   operations (the banked carry rows a preempted run resumes from live in
@@ -49,9 +49,10 @@ def choose_shard(policy: str, loads, home: int | None = None,
     """Pick the shard for one ticket.
 
     ``loads`` is the per-shard unfinished-work vector (backlog depth +
-    in-flight seats) at decision time; ``home`` is the ticket's existing
-    shard, if any — sticky affinity short-circuits every policy, so a
-    preempted/resumed ticket never migrates.  ``rr`` is the broker's
+    staged-unseated tickets + in-flight seats) at decision time; ``home``
+    is the ticket's existing shard, if any — sticky affinity
+    short-circuits every policy, so a preempted/resumed ticket never
+    migrates.  ``rr`` is the broker's
     monotone round-robin cursor.  Deterministic: equal loads resolve to
     the lowest shard id.
     """

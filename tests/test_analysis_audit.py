@@ -102,10 +102,32 @@ def test_r3_understands_antimask_negation():
     assert audit(fn, args, [MaskedReduceRule(m=m, mask_argnums=(1, 2))]) == []
 
 
+def test_r3_lowest_index_pick_is_a_selection_index():
+    """The lowest index among the top scores, min(where(s == top, iota,
+    m)), selects like an argmax: a one-hot of it masks an M-reduce.  With
+    the fill drawn from data instead of a scalar, it does not."""
+    m = 8
+
+    def pick(y, valid, fill):
+        s = jnp.where(valid, y, -jnp.inf)
+        iota = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
+        top = jnp.max(s, axis=1, keepdims=True)
+        sel = jnp.min(jnp.where(s == top, iota, fill), axis=1)
+        return jnp.sum(jnp.where(iota == sel[:, None], y, 0.0))
+
+    args = (jnp.ones((1, m)), jnp.zeros((1, m), bool))
+    rules = [MaskedReduceRule(m=m, mask_argnums=(1,))]
+    assert audit(lambda y, valid: pick(y, valid, m), args, rules) == []
+    found = audit(lambda y, valid: pick(y, valid, y.astype(jnp.int32)),
+                  args, rules)
+    assert {(f.rule, f.primitive) for f in found} == {
+        ("R3", "reduce_min"), ("R3", "reduce_sum")}
+
+
 def test_r4_flags_f64_and_callbacks():
     from repro.analysis import NoF64NoCallbackRule
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         f64 = audit(lambda x: x.astype(jnp.float64).astype(jnp.float32),
                     (jnp.float32(1.0),), [NoF64NoCallbackRule()])
     assert [f.rule for f in f64] == ["R4"]
@@ -143,7 +165,7 @@ def test_signature_stable_under_retrace_and_distinct_for_distinct_programs():
 
 
 def test_signature_ignores_cosmetic_names():
-    """Wrapping in pjit with a different function name must not change the
+    """Wrapping in jit with a different function name must not change the
     canonical signature (the `name` param is cosmetic)."""
     def body(x):
         return x * 2.0

@@ -1,9 +1,8 @@
 """Direct unit tests for the repro.compat shims.
 
-Each shim exists to absorb a jax API drift; these tests pin the shim's
-*behavior* (return shapes/types and fallback equivalence) rather than the
-jax version, so a toolchain bump that changes which branch runs still has
-to preserve the contract.
+Each shim owns one jax API whose spelling drifted across releases; these
+tests pin the shim's *behavior* (return shapes and types) rather than the
+jax version, so the next drift must preserve the contract.
 """
 
 import jax
@@ -87,9 +86,7 @@ class _FakeCompiled:
 
 @pytest.mark.parametrize("ret,expect", [
     ({"flops": 8.0}, {"flops": 8.0}),            # dict-returning jax
-    ([{"flops": 8.0}], {"flops": 8.0}),          # 0.4.x list-of-dict
-    (({"flops": 8.0},), {"flops": 8.0}),         # tuple variant
-    ([], {}),                                    # empty analysis
+    ({}, {}),                                    # empty analysis
     (None, {}),                                  # missing analysis
 ])
 def test_cost_analysis_dict_normalizes_both_shapes(ret, expect):

@@ -290,3 +290,47 @@ def test_fused_selector_bit_parity(policy, la, timeout, padded):
     for k in da:
         np.testing.assert_array_equal(da[k], db[k], err_msg=k)
         assert da[k].tobytes() == db[k].tobytes(), k   # bitwise, not just ==
+
+
+@pytest.mark.parametrize("emit_full", [False, True])
+def test_select_step_kernel_lane_and_block_invariant(emit_full):
+    """Under ``vmap`` over two lanes (how the R-lane selector calls it),
+    with a state count that is no multiple of the block (state padding,
+    several grid blocks, per-lane t_max/floor), each lane's outputs equal
+    a one-lane call that holds every state in one block, bit for bit."""
+    from repro.core import lookahead
+    from repro.core.lookahead import Settings, space_arrays
+    from repro.kernels.select_step.kernel import select_step_call
+    job = _selector_job(5)
+    points, left, thr, u = space_arrays(job.space, job.unit_price)
+    m = job.space.n_points
+    s = Settings(n_trees=3, depth=3, k_gh=2)
+    lanes, s_dim = 2, 1 if emit_full else 21
+    rng = np.random.default_rng(5)
+    y = jnp.asarray(rng.uniform(0.1, 2.0, (lanes, s_dim, m)), jnp.float32)
+    obs = jnp.asarray(rng.random((lanes, s_dim, m)) < 0.4)
+    cens = obs & jnp.asarray(rng.random((lanes, s_dim, m)) < 0.2)
+    beta = jnp.asarray(rng.uniform(0.5, 3.0, (lanes, s_dim)), jnp.float32)
+    bf = jnp.asarray(rng.uniform(0.2, 1.0, (lanes, s_dim)), jnp.float32)
+    t_max = jnp.asarray([job.t_max, 0.7 * job.t_max], jnp.float32)
+    floor = jnp.asarray([1e-3, 2e-2], jnp.float32)
+    xi = jnp.asarray(gauss_hermite(2)[0])
+    params = jax.vmap(lambda k, yy, oo: lookahead._fit_batch_params(
+        k, yy, oo, points, left, thr, s))(
+            jax.random.split(jax.random.PRNGKey(1), lanes), y, obs)
+    args = (params.feat, params.thr, params.leaf, y, obs, cens, beta, bf,
+            t_max, floor)
+
+    def call(f, th, lf, yy, oo, c, b, bb, t, fl, bs):
+        return select_step_call(f, th, lf, yy, oo, b, bb, points, u, t, fl,
+                                xi, c, emit_full=emit_full, want_nodes=True,
+                                bs=bs, interpret=True)
+
+    got = jax.vmap(lambda *a: call(*a, bs=8))(*args)
+    for lane in range(lanes):
+        want = call(*(a[lane] for a in args), bs=32)
+        assert len(got) == len(want)
+        for g, w in zip(got, want):
+            g = np.asarray(g[lane])
+            assert g.shape == w.shape and g.dtype == w.dtype
+            assert g.tobytes() == np.asarray(w).tobytes()

@@ -9,6 +9,9 @@ a service-wide (not per-shard) cap that raises :class:`QueueFull`
 deterministically.
 """
 
+import threading
+import time
+
 import pytest
 
 from repro.core import RunRequest, Settings, run_queue
@@ -163,3 +166,63 @@ def test_backpressure_is_service_wide_and_deterministic():
     svc.drain()
     for t in (t0, t1, t2, t3):
         assert t.state == "done"
+
+
+@pytest.mark.parametrize("window", ["before_seat", "device_queue"])
+def test_least_backlog_counts_staged_tickets_while_pump_runs(window):
+    """Submitting to a live service: a ticket the pump has staged is out of
+    its admission buffer before it holds a slot — briefly before seating,
+    or for a whole segment while it waits in the device queue.  Placement
+    must still count it, or the next submit piles onto the same shard.
+    Shard 0's engine is held inside that window while the next ticket is
+    submitted."""
+    jobs = _jobs()
+    s = Settings(policy="la0", la=0, k_gh=2)
+    reqs = _reqs(jobs, 4, seed0=440)
+    reqs[:2] = [RunRequest(q.job, q.seed, budget_b=5.0)  # 10 and 8 steps
+                for q in reqs[:2]]
+    svc = StreamingTuner(jobs, s, ServiceConfig(
+        lane_slots=1, queue_capacity=2, step_quota=1, num_shards=2))
+    eng = svc._engines.shards[0]
+    entered, release = threading.Event(), threading.Event()
+
+    if window == "before_seat":
+        run_segment = eng.run_segment
+
+        def hooked(staged, *a):
+            if staged and not entered.is_set():
+                entered.set()
+                release.wait(60)
+            return run_segment(staged, *a)
+        eng.run_segment = hooked
+        svc.start()
+        first = [svc.submit(reqs[0])]
+    else:
+        seat = eng._seat
+
+        def hooked(staged):
+            rest, n = seat(staged)
+            if rest and not entered.is_set():
+                entered.set()
+                release.wait(60)
+            return rest, n
+        eng._seat = hooked
+        first = [svc.submit(q) for q in reqs[:2]]    # one per shard
+        assert [t.shard for t in first] == [0, 1]
+        svc.start()
+        deadline = time.perf_counter() + 60
+        while not all(e.in_flight() for e in svc._engines.shards):
+            assert time.perf_counter() < deadline
+            time.sleep(0.01)
+        first.append(svc.submit(reqs[2]))     # tie -> shard 0's queue
+        assert first[-1].shard == 0
+    try:
+        assert entered.wait(60), "the pump never staged into shard 0"
+        late = svc.submit(reqs[3])
+        release.set()
+        assert late.shard == 1
+        for t in first + [late]:
+            t.result(timeout=120)
+    finally:
+        release.set()
+        svc.stop()

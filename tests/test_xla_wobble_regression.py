@@ -86,7 +86,7 @@ def test_budget_filter_is_zspace_not_cdf():
     vectorization differs per geometry.  Pinned structurally through the
     determinism auditor: no erf-family primitive anywhere in the traced
     program (including sub-jaxprs — a ``"erf" not in str(jaxpr)`` pin would
-    miss one buried in a pjit call and false-positive on variable names)."""
+    miss one buried in a jit call and false-positive on variable names)."""
     from repro.analysis import ForbiddenPrimitivesRule, audit
 
     findings = audit(
