@@ -38,14 +38,10 @@ oracle for every surviving run (the preempted-then-resumed one included,
 ``spend_trajectory`` and all), preemption/resume/cancel counters all
 exercised and balanced, and no leaked lane slots.
 
-A sixth section gates the **observability overhead** (ISSUE-9, the
-zero-perturbation rule made quantitative): the same streamed trace with
-the flight recorder ON must hold >= 0.95x the trace-off steps/sec
-(best-of-interleaved repeats, so one scheduler hiccup cannot fail the
-gate) and replay it bit for bit.  Any drift gate in this file that trips
-freezes its evidence via ``repro.obs.dump_divergence`` before reporting.
+Any drift gate in this file that trips freezes its evidence via
+``repro.obs.dump_divergence`` before reporting.
 
-A seventh section gates **sharded serving** (ISSUE-10): the bursty trace
+A sixth section gates **sharded serving**: the bursty trace
 through a 2-shard fleet (one resident engine per device, segments
 overlapping across devices) must hold >= 1.6x the single-shard aggregate
 steps/sec with zero outcome drift — shard count is capacity, never a
@@ -304,62 +300,6 @@ def lifecycle_section(quick, out):
     csv_line("streaming", "lifecycle_slot_leaks", leaks)
 
 
-def obs_overhead_section(quick, out):
-    """Obs-overhead gate (ISSUE-9): trace-on steps/sec >= 0.95x trace-off
-    on the same streamed trace, measured best-of-interleaved-repeats so
-    shared machine noise hits both sides alike.  Parity between the two
-    runs is a hard zero: on drift the trace-on flight record plus field
-    diffs are frozen via ``dump_divergence`` before the gate reports."""
-    jobs = [synthetic_job(85 + k, n_a=8, n_b=8) for k in range(2)]
-    s = Settings(policy="lynceus", la=1, k_gh=3, refit="frozen")
-    n_bursts = 2 if quick else 4
-    bursts = _trace(jobs, n_bursts, seed0=85001)
-    base = dict(lane_slots=LANE_SLOTS, queue_capacity=4 * LANE_SLOTS,
-                step_quota=4)
-
-    def measure(svc):
-        svc.recorder.clear()
-        svc.reset_metrics()
-        t0 = time.perf_counter()
-        outs = _run_stream(svc, bursts)
-        wall = time.perf_counter() - t0
-        return sum(o.nex for o in outs) / wall, outs
-
-    svc_off = StreamingTuner(jobs, s, ServiceConfig(**base))
-    svc_on = StreamingTuner(jobs, s, ServiceConfig(
-        **base, trace=True, trace_capacity=1 << 15))
-    warm = _trace(jobs, 1, seed0=95001)           # warm compiles both sides
-    _run_stream(svc_off, warm)
-    _run_stream(svc_on, warm)
-
-    best_off = best_on = 0.0
-    for _ in range(2 if quick else 3):            # interleaved repeats
-        sps_off, outs_off = measure(svc_off)
-        sps_on, outs_on = measure(svc_on)
-        best_off = max(best_off, sps_off)
-        best_on = max(best_on, sps_on)
-
-    drift = sum(not outcomes_equal(a, b)
-                for a, b in zip(outs_off, outs_on))
-    if drift:
-        dump_divergence("obs_overhead_drift", expected=outs_off,
-                        actual=outs_on, recorder=svc_on.recorder,
-                        context={"bench": "streaming_throughput",
-                                 "section": "obs_overhead"})
-    ratio = best_on / best_off
-    events = sum(svc_on.recorder.counts().values())
-    out["obs_overhead"] = {
-        "requests": sum(len(b) for b in bursts),
-        "steps_per_s_trace_off": best_off, "steps_per_s_trace_on": best_on,
-        "trace_on_ratio": ratio, "events_recorded": events,
-        "drifting_runs": drift,
-    }
-    csv_line("streaming", "obs_trace_events", events)
-    csv_line("streaming", "obs_drifting_runs", drift)
-    csv_line("streaming", "obs_trace_on_ratio", round(ratio, 3))
-    csv_line("streaming", "obs_overhead_le_5pct", ratio >= 0.95)
-
-
 def sharded_section(quick, out):
     """Sharded-serving scaling gate (ISSUE-10): the bursty trace through a
     2-shard fleet — one resident engine per device, segments overlapping
@@ -501,7 +441,6 @@ def main(n_runs=20, quick=False):
     mixed_geometry_stream(n_bursts=4 if quick else 6, out=out)
     fused_selector_section(quick, out)
     lifecycle_section(quick, out)
-    obs_overhead_section(quick, out)
     sharded_section(quick, out)
     write_json("streaming", out)
     write_bench_json("streaming", out)

@@ -91,12 +91,12 @@ def serve(jobs, settings, config, requests):
     return outcomes, shards, engines, wall
 
 
-def compile_segment(engine):
-    """Ahead-of-time compile of ``engine``'s segment program; returns
-    (compiled, seconds)."""
-    from repro.core.optimizer import _episode_segment
+def compile_segment(jobs, settings, config):
+    """The deployment's segment program, compiled ahead of time
+    (``repro.obs.segment_program``); returns (compiled, seconds)."""
+    from repro.obs import segment_program
     t0 = time.perf_counter()
-    compiled = _episode_segment.lower(*engine.segment_args()).compile()
+    compiled = segment_program(jobs, settings, config)
     return compiled, time.perf_counter() - t0
 
 
@@ -118,7 +118,6 @@ def one_chip_phases(device) -> None:
     from repro.core import lookahead, optimize
     from repro.jobs import tensorflow_jobs
     from repro.service import ServiceConfig
-    from repro.service.engine import SegmentEngine
 
     jobs = tensorflow_jobs(0)
     check(all(j.space.n_points == 384 for j in jobs),
@@ -132,7 +131,7 @@ def one_chip_phases(device) -> None:
     say("lane_slots", config.lane_slots)
 
     # 1. The segment program, compiled ahead of time.
-    compiled, compile_s = compile_segment(SegmentEngine(jobs, s, config))
+    compiled, compile_s = compile_segment(jobs, s, config)
     mem = compiled.memory_analysis()
     program_bytes = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
                      + mem.output_size_in_bytes)
@@ -168,7 +167,7 @@ def one_chip_phases(device) -> None:
     ref_s = dataclasses.replace(s, fused_selector="ref")
     check(lookahead._fused_mode(ref_s) is None, "ref mode is not unfused")
     one_lane = ServiceConfig(lane_slots=1)
-    _, compile_s = compile_segment(SegmentEngine(jobs, ref_s, one_lane))
+    _, compile_s = compile_segment(jobs, ref_s, one_lane)
     say(f"unfused one-lane segment compile_seconds {compile_s:.3f}")
     (ref_out,), _, _, wall = serve(jobs, ref_s, one_lane, REQUESTS[:1])
     say(f"unfused one-lane service wall_seconds {wall:.3f}")

@@ -48,6 +48,7 @@ from repro.core import acquisition as acq
 from repro.core import trees
 from repro.kernels.dispatch import resolve_mode as _resolve_kernel_mode
 from repro.kernels.select_step.kernel import select_step_call
+from repro.obs.scopes import scope
 
 __all__ = ["Settings", "select_next", "select_next_batched", "make_selector",
            "make_batch_selector", "space_arrays", "space_valid",
@@ -273,13 +274,14 @@ def _recurse(key, y_b, m_b, beta_b, bf_b, depth_left, *, points, left,
         # Gamma -> quantized argmax) runs in one Pallas program.
         params = _fit_batch_params(k_fit, y_b, m_b, points, left,
                                    thresholds, s)
-        out = select_step_call(
-            params.feat, params.thr, params.leaf, y_b, m_b, beta_b, bf_b,
-            points, u, t_max, floor, jnp.asarray(xi), cens=cens_b,
-            valid=valid, conf=s.conf, cens_rel=s.cens_sigma_rel,
-            score_mode="eic", use_budget=True, emit_full=False,
-            want_nodes=depth_left > 0, bs=s.fused_block_states,
-            interpret=(fused == "interpret"))
+        with scope("select_step"):
+            out = select_step_call(
+                params.feat, params.thr, params.leaf, y_b, m_b, beta_b,
+                bf_b, points, u, t_max, floor, jnp.asarray(xi), cens=cens_b,
+                valid=valid, conf=s.conf, cens_rel=s.cens_sigma_rel,
+                score_mode="eic", use_budget=True, emit_full=False,
+                want_nodes=depth_left > 0, bs=s.fused_block_states,
+                interpret=(fused == "interpret"))
         sel, has_cand, eic_sel, mu_sel, sig_sel = out[:5]
         r0 = jnp.where(has_cand, eic_sel, 0.0)
         c0 = jnp.where(has_cand, mu_sel, 0.0)
@@ -388,15 +390,16 @@ def _select_next_fused(key, y, obs_mask, beta, points, left, thresholds, u,
     lookahead = s.policy == "lynceus" and s.la > 0
     xi, w = acq.gauss_hermite(s.k_gh)
 
-    out = select_step_call(
-        params.feat[None], params.thr[None], params.leaf[None], y[None],
-        obs[None], jnp.asarray(beta, jnp.float32)[None], best_feas[None],
-        points, u, t_max, floor, jnp.asarray(xi),
-        cens=None if cens is None else cens.astype(bool)[None],
-        valid=valid, conf=s.conf, cens_rel=s.cens_sigma_rel,
-        score_mode=score_mode, use_budget=use_budget, emit_full=True,
-        want_nodes=lookahead, bs=s.fused_block_states,
-        interpret=(mode == "interpret"))
+    with scope("select_step"):
+        out = select_step_call(
+            params.feat[None], params.thr[None], params.leaf[None], y[None],
+            obs[None], jnp.asarray(beta, jnp.float32)[None],
+            best_feas[None], points, u, t_max, floor, jnp.asarray(xi),
+            cens=None if cens is None else cens.astype(bool)[None],
+            valid=valid, conf=s.conf, cens_rel=s.cens_sigma_rel,
+            score_mode=score_mode, use_budget=use_budget, emit_full=True,
+            want_nodes=lookahead, bs=s.fused_block_states,
+            interpret=(mode == "interpret"))
     mu0, sig0, eic0 = out[0][0], out[1][0], out[2][0]
     ystar0, cand0, sel0, has0 = out[3][0], out[4][0], out[5][0], out[6][0]
     diagnostics = {"mu": acq.quantize_scores(mu0),
@@ -612,8 +615,9 @@ def select_next_batched(keys, y, obs_mask, beta, points, left, thresholds, u,
                          "geometry bucketing and require a validity mask")
 
     def one(k, y_r, m_r, b_r, c_r, u_r, t_r, p_r, l_r, th_r, v_r):
-        return _select_next_impl(k, y_r, m_r, b_r, p_r, l_r, th_r,
-                                 u_r, t_r, s, c_r, v_r)
+        with scope("speculate"):
+            return _select_next_impl(k, y_r, m_r, b_r, p_r, l_r, th_r,
+                                     u_r, t_r, s, c_r, v_r)
 
     sp_ax = 0 if per_slot_space else None
     return jax.vmap(one, in_axes=(0, 0, 0, 0,

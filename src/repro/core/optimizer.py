@@ -62,6 +62,7 @@ import numpy as np
 
 from repro.core import lookahead, trees
 from repro.core.space import GeometryBucket, latin_hypercube_indices
+from repro.obs.scopes import scope
 
 if TYPE_CHECKING:  # avoid the core <-> jobs import cycle at runtime
     from repro.jobs.tables import JobTable
@@ -744,119 +745,120 @@ def _episode_segment(carry, queue, qtail, evict, low_water, step_quota,
     of seating *and* arrival order; the caller re-keys results by run id,
     never by slot.
     """
-    l_dim, m_dim = carry["y"].shape
-    c_dim = queue["y"].shape[0]
-    n_out = l_dim + c_dim
-    lanes = jnp.arange(l_dim)
+    with scope("lynceus"), scope("segment"):
+        l_dim, m_dim = carry["y"].shape
+        c_dim = queue["y"].shape[0]
+        n_out = l_dim + c_dim
+        lanes = jnp.arange(l_dim)
 
-    def cond(st):
-        pending = qtail - st["qhead"]
-        has_work = st["active"].any() | (pending > 0)
-        return (has_work & (st["steps"] < step_quota)
-                & ((st["steps"] == 0) | (pending >= low_water)))
+        def cond(st):
+            pending = qtail - st["qhead"]
+            has_work = st["active"].any() | (pending > 0)
+            return (has_work & (st["steps"] < step_quota)
+                    & ((st["steps"] == 0) | (pending >= low_water)))
 
-    def body(st):
-        split = jax.vmap(jax.random.split)(st["key"])       # [L, 2, 2]
-        key, sub = split[:, 0], split[:, 1]
-        rid_safe = jnp.maximum(st["rid"], 0)
-        u_l, t_l, jid = lookahead.slot_price_rows(job_ids, rid_safe, u,
-                                                  t_max)
-        if jid is not None and points.ndim == 3:
-            # Geometry-bucketed queue: each seat selects on its own job's
-            # padded space tensors and validity row.
-            pts_l, left_l, thr_l = points[jid], left[jid], thresholds[jid]
-            val_l = valid[jid]
-        else:
-            pts_l, left_l, thr_l, val_l = points, left, thresholds, valid
-        idx, sel_ok, diag = lookahead.select_next_batched(
-            sub, st["y"], st["mask"], jnp.maximum(st["beta"], 0.0),
-            pts_l, left_l, thr_l, u_l, t_l, s,
-            st["cens"] if s.timeout else None, val_l)
-        if jid is None:
-            c = cost[idx]
-            t_run = runtime[idx] if s.timeout else None
-            u_at = u[idx] if s.timeout else None
-        else:
-            pick = lambda tab: jnp.take_along_axis(
-                tab[jid], idx[:, None], axis=1)[:, 0]
-            c = pick(cost)
-            t_run = pick(runtime) if s.timeout else None
-            u_at = pick(u) if s.timeout else None
-        step, alive = _alg1_step(
-            st, idx, c, t_run, u_at, sel_ok,
-            diag["timeout"] if s.timeout else None, s, lanes, m_dim)
+        def body(st):
+            split = jax.vmap(jax.random.split)(st["key"])       # [L, 2, 2]
+            key, sub = split[:, 0], split[:, 1]
+            rid_safe = jnp.maximum(st["rid"], 0)
+            u_l, t_l, jid = lookahead.slot_price_rows(job_ids, rid_safe, u,
+                                                      t_max)
+            if jid is not None and points.ndim == 3:
+                # Geometry-bucketed queue: each seat selects on its own job's
+                # padded space tensors and validity row.
+                pts_l, left_l, thr_l = points[jid], left[jid], thresholds[jid]
+                val_l = valid[jid]
+            else:
+                pts_l, left_l, thr_l, val_l = points, left, thresholds, valid
+            idx, sel_ok, diag = lookahead.select_next_batched(
+                sub, st["y"], st["mask"], jnp.maximum(st["beta"], 0.0),
+                pts_l, left_l, thr_l, u_l, t_l, s,
+                st["cens"] if s.timeout else None, val_l)
+            if jid is None:
+                c = cost[idx]
+                t_run = runtime[idx] if s.timeout else None
+                u_at = u[idx] if s.timeout else None
+            else:
+                pick = lambda tab: jnp.take_along_axis(
+                    tab[jid], idx[:, None], axis=1)[:, 0]
+                c = pick(cost)
+                t_run = pick(runtime) if s.timeout else None
+                u_at = pick(u) if s.timeout else None
+            step, alive = _alg1_step(
+                st, idx, c, t_run, u_at, sel_ok,
+                diag["timeout"] if s.timeout else None, s, lanes, m_dim)
 
-        # A slot's run terminated this step -> bank it by run id.
-        finished = st["active"] & ~alive
-        tgt = jnp.where(finished, rid_safe, n_out)          # OOB rows dropped
-        out = {"out_done": st["out_done"].at[tgt].set(True, mode="drop"),
-               "out_beta": st["out_beta"].at[tgt].set(step["beta"],
-                                                      mode="drop"),
-               "out_nexp": st["out_nexp"].at[tgt].set(step["n_exp"],
-                                                      mode="drop"),
-               "out_expl": st["out_expl"].at[tgt].set(step["explored"],
-                                                      mode="drop")}
+            # A slot's run terminated this step -> bank it by run id.
+            finished = st["active"] & ~alive
+            tgt = jnp.where(finished, rid_safe, n_out)      # OOB rows dropped
+            out = {"out_done": st["out_done"].at[tgt].set(True, mode="drop"),
+                   "out_beta": st["out_beta"].at[tgt].set(step["beta"],
+                                                          mode="drop"),
+                   "out_nexp": st["out_nexp"].at[tgt].set(step["n_exp"],
+                                                          mode="drop"),
+                   "out_expl": st["out_expl"].at[tgt].set(step["explored"],
+                                                          mode="drop")}
+            if s.timeout:
+                out["out_cexpl"] = st["out_cexpl"].at[tgt].set(step["cexpl"],
+                                                               mode="drop")
+                out["out_bexpl"] = st["out_bexpl"].at[tgt].set(step["bexpl"],
+                                                               mode="drop")
+
+            # Refill seatless slots (just finished, or idle from an earlier
+            # drain) from the queue head, in slot order: the k-th seatable slot
+            # (k = rank among seatable) takes queue row qhead + k.
+            seatable = ~alive
+            rank = jnp.cumsum(seatable.astype(jnp.int32)) - 1
+            cand = st["qhead"] + rank
+            got = seatable & (cand < qtail)
+            src = jnp.where(got, cand, 0)
+            fill = lambda init, cur: jnp.where(
+                got.reshape((l_dim,) + (1,) * (cur.ndim - 1)), init[src], cur)
+            nxt = {"key": fill(queue["keys"], key),
+                   "rid": jnp.where(got, l_dim + cand,
+                                    jnp.where(finished, -1, st["rid"])),
+                   "active": alive | got,
+                   "qhead": st["qhead"] + got.sum(dtype=jnp.int32),
+                   "steps": st["steps"] + 1,
+                   "busy": st["busy"] + st["active"].sum(dtype=jnp.int32)}
+            for k, v in step.items():
+                nxt[k] = fill(queue[k], v)
+            nxt.update(out)
+            return nxt
+
+        st0 = dict(carry)
+        st0.update(steps=jnp.int32(0), busy=jnp.int32(0),
+                   out_done=jnp.zeros((n_out,), bool),
+                   out_beta=jnp.zeros((n_out,), jnp.float32),
+                   out_nexp=jnp.zeros((n_out,), jnp.int32),
+                   out_expl=jnp.full((n_out, m_dim), -1, jnp.int32))
         if s.timeout:
-            out["out_cexpl"] = st["out_cexpl"].at[tgt].set(step["cexpl"],
-                                                           mode="drop")
-            out["out_bexpl"] = st["out_bexpl"].at[tgt].set(step["bexpl"],
-                                                           mode="drop")
+            st0["out_cexpl"] = jnp.zeros((n_out, m_dim), bool)
+            st0["out_bexpl"] = jnp.zeros((n_out, m_dim), jnp.float32)
 
-        # Refill seatless slots (just finished, or idle from an earlier
-        # drain) from the queue head, in slot order: the k-th seatable slot
-        # (k = rank among seatable) takes queue row qhead + k.
-        seatable = ~alive
-        rank = jnp.cumsum(seatable.astype(jnp.int32)) - 1
-        cand = st["qhead"] + rank
-        got = seatable & (cand < qtail)
-        src = jnp.where(got, cand, 0)
-        fill = lambda init, cur: jnp.where(
-            got.reshape((l_dim,) + (1,) * (cur.ndim - 1)), init[src], cur)
-        nxt = {"key": fill(queue["keys"], key),
-               "rid": jnp.where(got, l_dim + cand,
-                                jnp.where(finished, -1, st["rid"])),
-               "active": alive | got,
-               "qhead": st["qhead"] + got.sum(dtype=jnp.int32),
-               "steps": st["steps"] + 1,
-               "busy": st["busy"] + st["active"].sum(dtype=jnp.int32)}
-        for k, v in step.items():
-            nxt[k] = fill(queue[k], v)
-        nxt.update(out)
-        return nxt
+        # Boundary eviction: bank flagged seats' partial state by run id
+        # (out_done stays False — these rows are partial, not completed) and
+        # free the seat before the loop so it refills like any drained slot.
+        kill = carry["active"] & evict
+        tgt0 = jnp.where(kill, jnp.maximum(carry["rid"], 0), n_out)
+        st0["out_beta"] = st0["out_beta"].at[tgt0].set(carry["beta"],
+                                                       mode="drop")
+        st0["out_nexp"] = st0["out_nexp"].at[tgt0].set(carry["n_exp"],
+                                                       mode="drop")
+        st0["out_expl"] = st0["out_expl"].at[tgt0].set(carry["explored"],
+                                                       mode="drop")
+        if s.timeout:
+            st0["out_cexpl"] = st0["out_cexpl"].at[tgt0].set(carry["cexpl"],
+                                                             mode="drop")
+            st0["out_bexpl"] = st0["out_bexpl"].at[tgt0].set(carry["bexpl"],
+                                                             mode="drop")
+        st0["active"] = carry["active"] & ~kill
+        st0["rid"] = jnp.where(kill, -1, carry["rid"])
 
-    st0 = dict(carry)
-    st0.update(steps=jnp.int32(0), busy=jnp.int32(0),
-               out_done=jnp.zeros((n_out,), bool),
-               out_beta=jnp.zeros((n_out,), jnp.float32),
-               out_nexp=jnp.zeros((n_out,), jnp.int32),
-               out_expl=jnp.full((n_out, m_dim), -1, jnp.int32))
-    if s.timeout:
-        st0["out_cexpl"] = jnp.zeros((n_out, m_dim), bool)
-        st0["out_bexpl"] = jnp.zeros((n_out, m_dim), jnp.float32)
-
-    # Boundary eviction: bank flagged seats' partial state by run id
-    # (out_done stays False — these rows are partial, not completed) and
-    # free the seat before the loop so it refills like any drained slot.
-    kill = carry["active"] & evict
-    tgt0 = jnp.where(kill, jnp.maximum(carry["rid"], 0), n_out)
-    st0["out_beta"] = st0["out_beta"].at[tgt0].set(carry["beta"],
-                                                   mode="drop")
-    st0["out_nexp"] = st0["out_nexp"].at[tgt0].set(carry["n_exp"],
-                                                   mode="drop")
-    st0["out_expl"] = st0["out_expl"].at[tgt0].set(carry["explored"],
-                                                   mode="drop")
-    if s.timeout:
-        st0["out_cexpl"] = st0["out_cexpl"].at[tgt0].set(carry["cexpl"],
-                                                         mode="drop")
-        st0["out_bexpl"] = st0["out_bexpl"].at[tgt0].set(carry["bexpl"],
-                                                         mode="drop")
-    st0["active"] = carry["active"] & ~kill
-    st0["rid"] = jnp.where(kill, -1, carry["rid"])
-
-    st = jax.lax.while_loop(cond, body, st0)
-    report = {k: st.pop(k) for k in list(st)
-              if k.startswith("out_") or k in ("steps", "busy")}
-    return st, report
+        st = jax.lax.while_loop(cond, body, st0)
+        report = {k: st.pop(k) for k in list(st)
+                  if k.startswith("out_") or k in ("steps", "busy")}
+        return st, report
 
 
 def _spaces_shared(jobs: list[JobTable]) -> bool:

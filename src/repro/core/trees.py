@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.core.acquisition import no_contract as _no_contract
 from repro.core.acquisition import quantize_scores as _quantize_scores
+from repro.obs.scopes import scope
 
 __all__ = [
     "ForestParams", "bootstrap_weights", "make_left_table", "fit_forest",
@@ -142,92 +143,104 @@ def _fit_one_tree(y: jax.Array, w: jax.Array, points: jax.Array,
     # level's per-child sums: one pinned fold per level serves both, and no
     # backend-ordered w·y contraction reaches a node total (a matvec here
     # split the lane-batched harness from the one-run oracle on the CPU).
-    sw_n = jnp.sum(w)[None]
-    wy = _no_contract(w * y)
-    swy_n = _pinned_sum0(wy)[None]
-    val = swy_n / jnp.maximum(sw_n, _EPS)
+    with scope("fit/route"):
+        sw_n = jnp.sum(w)[None]
+        wy = _no_contract(w * y)
+        swy_n = _pinned_sum0(wy)[None]
+        val = swy_n / jnp.maximum(sw_n, _EPS)
 
     feat_lvls, thr_lvls = [], []
     for lvl in range(depth):
         n = 2 ** lvl
-        onehot = (assign[:, None] == jnp.arange(n)[None, :]).astype(jnp.float32)
-        # Left-branch stats per (node, feature, threshold).  Contract the M
-        # dimension as one [n, M] @ [M, F*T] matmul per statistic: this keeps
-        # intermediates at O(n·F·T) instead of the naive einsum's O(M·F·T)
-        # per node, which is what makes the vmap over thousands of
-        # speculative states affordable (and MXU-friendly on TPU).
-        left_flat = left.reshape(m, f_dims * t_dims)
-        stats = jnp.stack([w, wy], axis=0)               # [2, M]
-        # Full f32 products: a TPU's default precision would round w·y to
-        # bfloat16 first (~2^-9 relative), far above the noise floor and
-        # score grid the split argmax relies on.  A no-op on the CPU.
-        node_stats = jnp.matmul(onehot.T[None, :, :] * stats[:, None, :],
-                                left_flat,
-                                precision=jax.lax.Precision.HIGHEST)
-        sl_w, sl_wy = (node_stats.reshape(2, n, f_dims, t_dims)[i]
-                       for i in range(2))
-        sr_w = sw_n[:, None, None] - sl_w
-        sr_wy = swy_n[:, None, None] - sl_wy
-        # Variance-reduction gain in its decomposition form,
-        #   SSE_p - SSE_l - SSE_r = (w_l w_r / w_p) (mean_l - mean_r)^2,
-        # which is algebraically identical to differencing the three SSEs but
-        # free of their catastrophic cancellation: the gain's floating-point
-        # wobble is *relative* (~1 ulp), not absolute at the scale of
-        # ulp(sum w y^2).  That matters because XLA re-fuses this program
-        # differently per batch geometry (1-run oracle vs R-run harness), and
-        # the split argmax below must not flip between the two.
-        ml = sl_wy / jnp.maximum(sl_w, _EPS)
-        mr = sr_wy / jnp.maximum(sr_w, _EPS)
-        gain = (sl_w * sr_w / jnp.maximum(sw_n[:, None, None], _EPS)
-                * (ml - mr) ** 2)
-        # Noise floor: when a node's observed values are (near-)constant,
-        # ml - mr is itself a catastrophic cancellation and every "gain" is
-        # pure rounding noise with O(1) relative error — snap those to an
-        # exact 0 so the argmax ties deterministically instead of ranking
-        # noise.  1e-10 of the node's w·mean^2 scale sits ~4 orders above
-        # the (1e-7)^2 relative noise and far below any meaningful gain.
-        scale = (swy_n * swy_n / jnp.maximum(sw_n, _EPS))[:, None, None]
-        gain = jnp.where(gain < scale * 1e-10, 0.0, gain)
-        valid = (sl_w >= min_weight) & (sr_w >= min_weight)
-        gain = jnp.where(valid, gain, -jnp.inf)
-        # Quantized argmax (see acquisition.quantize_scores): collapse
-        # geometry-dependent last-ulp wobble into exact ties, which break by
-        # lowest index identically in every compilation context.
-        flat = _quantize_scores(gain).reshape(n, f_dims * t_dims)
-        best = jnp.argmax(flat, axis=1)
-        best_gain = jnp.max(flat, axis=1)                # == flat[best]
-        f_sel = (best // t_dims).astype(jnp.int32)
-        degenerate = ~jnp.isfinite(best_gain)
-        f_sel = jnp.where(degenerate, 0, f_sel)
+        with scope("fit/split_stats"):
+            onehot = (assign[:, None]
+                      == jnp.arange(n)[None, :]).astype(jnp.float32)
+            # Left-branch stats per (node, feature, threshold).  Contract
+            # the M dimension as one [n, M] @ [M, F*T] matmul per
+            # statistic: this keeps intermediates at O(n·F·T) instead of
+            # the naive einsum's O(M·F·T) per node, which is what makes the
+            # vmap over thousands of speculative states affordable (and
+            # MXU-friendly on TPU).
+            left_flat = left.reshape(m, f_dims * t_dims)
+            stats = jnp.stack([w, wy], axis=0)               # [2, M]
+            # Full f32 products: a TPU's default precision would round w·y
+            # to bfloat16 first (~2^-9 relative), far above the noise floor
+            # and score grid the split argmax relies on.  A no-op on the
+            # CPU.
+            node_stats = jnp.matmul(
+                onehot.T[None, :, :] * stats[:, None, :], left_flat,
+                precision=jax.lax.Precision.HIGHEST)
+        with scope("fit/split_choice"):
+            sl_w, sl_wy = (node_stats.reshape(2, n, f_dims, t_dims)[i]
+                           for i in range(2))
+            sr_w = sw_n[:, None, None] - sl_w
+            sr_wy = swy_n[:, None, None] - sl_wy
+            # Variance-reduction gain in its decomposition form,
+            #   SSE_p - SSE_l - SSE_r = (w_l w_r / w_p) (mean_l - mean_r)^2,
+            # which is algebraically identical to differencing the three
+            # SSEs but free of their catastrophic cancellation: the gain's
+            # floating-point wobble is *relative* (~1 ulp), not absolute at
+            # the scale of ulp(sum w y^2).  That matters because XLA
+            # re-fuses this program differently per batch geometry (1-run
+            # oracle vs R-run harness), and the split argmax below must not
+            # flip between the two.
+            ml = sl_wy / jnp.maximum(sl_w, _EPS)
+            mr = sr_wy / jnp.maximum(sr_w, _EPS)
+            gain = (sl_w * sr_w / jnp.maximum(sw_n[:, None, None], _EPS)
+                    * (ml - mr) ** 2)
+            # Noise floor: when a node's observed values are
+            # (near-)constant, ml - mr is itself a catastrophic
+            # cancellation and every "gain" is pure rounding noise with
+            # O(1) relative error — snap those to an exact 0 so the argmax
+            # ties deterministically instead of ranking noise.  1e-10 of
+            # the node's w·mean^2 scale sits ~4 orders above the (1e-7)^2
+            # relative noise and far below any meaningful gain.
+            scale = (swy_n * swy_n / jnp.maximum(sw_n, _EPS))[:, None, None]
+            gain = jnp.where(gain < scale * 1e-10, 0.0, gain)
+            valid = (sl_w >= min_weight) & (sr_w >= min_weight)
+            gain = jnp.where(valid, gain, -jnp.inf)
+            # Quantized argmax (see acquisition.quantize_scores): collapse
+            # geometry-dependent last-ulp wobble into exact ties, which
+            # break by lowest index identically in every compilation
+            # context.
+            flat = _quantize_scores(gain).reshape(n, f_dims * t_dims)
+            best = jnp.argmax(flat, axis=1)
+            best_gain = jnp.max(flat, axis=1)                # == flat[best]
+            f_sel = (best // t_dims).astype(jnp.int32)
+            degenerate = ~jnp.isfinite(best_gain)
+            f_sel = jnp.where(degenerate, 0, f_sel)
 
-        feat_pad = jnp.zeros((width,), jnp.int32).at[:n].set(f_sel)
-        feat_lvls.append(feat_pad)
-        # Route points: go right iff NOT left of threshold.  Each node's
-        # split column of ``left`` comes out of an exact 0/1 one-hot
-        # contraction and each point reads its own node's row: a per-point
-        # gather into the [M, F, T] table is what a TPU lowers worst (it
-        # touches the whole table per point).
-        split_oh = (jnp.arange(f_dims * t_dims)[None, :]
-                    == best[:, None]).astype(jnp.float32)
-        node_left = ((split_oh @ left_flat.T > 0.5)
-                     | degenerate[:, None])                # [n, M]
-        goes_left = jnp.any(node_left & (onehot.T > 0.5), axis=0)
-        assign = 2 * assign + (~goes_left).astype(jnp.int32)
-        # Child means with parent fallback.
-        n2 = 2 * n
-        oh2 = (jnp.arange(n2)[:, None] == assign[None, :]).astype(jnp.float32)
-        sw2 = oh2 @ w                                    # [2n]
-        # One-hot masking (0/1 products are exact) + pinned fold keeps the
-        # child means bit-stable across compilation contexts; the matmul
-        # above may stay — its integer sums are exact in any order.  The
-        # product is laid out [2n, M] and folded over its minor M axis: a
-        # minor axis of 2n <= 16 would be padded to the TPU's 128-lane tile,
-        # an 8x-16x blow-up across thousands of speculative fits.
-        swy2_ = _pinned_sum0(oh2 * wy[None, :], axis=1)
-        parent = jnp.repeat(val, 2)
-        val = jnp.where(sw2 > min_weight - 1e-9,
-                        swy2_ / jnp.maximum(sw2, _EPS), parent)
-        sw_n, swy_n = sw2, swy2_
+            feat_pad = jnp.zeros((width,), jnp.int32).at[:n].set(f_sel)
+            feat_lvls.append(feat_pad)
+        with scope("fit/route"):
+            # Route points: go right iff NOT left of threshold.  Each
+            # node's split column of ``left`` comes out of an exact 0/1
+            # one-hot contraction and each point reads its own node's row:
+            # a per-point gather into the [M, F, T] table is what a TPU
+            # lowers worst (it touches the whole table per point).
+            split_oh = (jnp.arange(f_dims * t_dims)[None, :]
+                        == best[:, None]).astype(jnp.float32)
+            node_left = ((split_oh @ left_flat.T > 0.5)
+                         | degenerate[:, None])                # [n, M]
+            goes_left = jnp.any(node_left & (onehot.T > 0.5), axis=0)
+            assign = 2 * assign + (~goes_left).astype(jnp.int32)
+            # Child means with parent fallback.
+            n2 = 2 * n
+            oh2 = (jnp.arange(n2)[:, None]
+                   == assign[None, :]).astype(jnp.float32)
+            sw2 = oh2 @ w                                    # [2n]
+            # One-hot masking (0/1 products are exact) + pinned fold keeps
+            # the child means bit-stable across compilation contexts; the
+            # matmul above may stay — its integer sums are exact in any
+            # order.  The product is laid out [2n, M] and folded over its
+            # minor M axis: a minor axis of 2n <= 16 would be padded to the
+            # TPU's 128-lane tile, an 8x-16x blow-up across thousands of
+            # speculative fits.
+            swy2_ = _pinned_sum0(oh2 * wy[None, :], axis=1)
+            parent = jnp.repeat(val, 2)
+            val = jnp.where(sw2 > min_weight - 1e-9,
+                            swy2_ / jnp.maximum(sw2, _EPS), parent)
+            sw_n, swy_n = sw2, swy2_
         # Store threshold as an actual value for standalone prediction. We
         # need the numeric threshold: gather from the shared grid is not
         # available here (left is boolean), so thresholds are passed in
@@ -256,12 +269,6 @@ def fit_forest(key: jax.Array, y: jax.Array, obs_mask: jax.Array,
     """
     m = y.shape[0]
     width = 2 ** (depth - 1) if depth > 0 else 1
-    obs = obs_mask.astype(jnp.float32)
-    boot = bootstrap_weights(key, n_trees, m)
-    w = boot * obs[None, :]
-    # Guard: a tree whose bootstrap came up all-zero falls back to plain obs.
-    dead = jnp.sum(w, axis=1, keepdims=True) < min_weight
-    w = jnp.where(dead, obs[None, :], w)
 
     def one(wi):
         assign, leaf_vals, feat_lvls, thr_meta = _fit_one_tree(
@@ -269,17 +276,29 @@ def fit_forest(key: jax.Array, y: jax.Array, obs_mask: jax.Array,
         feat = jnp.stack(feat_lvls) if depth > 0 else jnp.zeros((0, width), jnp.int32)
         thr_rows = []
         thr_flat = thresholds.reshape(-1)
-        for (best, degenerate, n) in thr_meta:
-            # The split's threshold as an exact one-hot select (a max over
-            # one value and -infs): a TPU gathers per node very slowly.
-            hit = jnp.arange(thr_flat.shape[0])[None, :] == best[:, None]
-            tv = jnp.max(jnp.where(hit, thr_flat[None, :], -jnp.inf), axis=1)
-            tv = jnp.where(degenerate, jnp.inf, tv)
-            thr_rows.append(jnp.full((width,), jnp.inf).at[:n].set(tv))
+        with scope("fit/split_choice"):
+            for (best, degenerate, n) in thr_meta:
+                # The split's threshold as an exact one-hot select (a max
+                # over one value and -infs): a TPU gathers per node very
+                # slowly.
+                hit = jnp.arange(thr_flat.shape[0])[None, :] == best[:, None]
+                tv = jnp.max(jnp.where(hit, thr_flat[None, :], -jnp.inf),
+                             axis=1)
+                tv = jnp.where(degenerate, jnp.inf, tv)
+                thr_rows.append(jnp.full((width,), jnp.inf).at[:n].set(tv))
         thr = jnp.stack(thr_rows) if depth > 0 else jnp.zeros((0, width), jnp.float32)
         return feat, thr, leaf_vals, assign
 
-    feat, thr, leaf, assign = jax.vmap(one)(w)
+    with scope("fit"):
+        obs = obs_mask.astype(jnp.float32)
+        with scope("fit/bootstrap"):
+            boot = bootstrap_weights(key, n_trees, m)
+            w = boot * obs[None, :]
+            # Guard: a tree whose bootstrap came up all-zero falls back to
+            # plain obs.
+            dead = jnp.sum(w, axis=1, keepdims=True) < min_weight
+            w = jnp.where(dead, obs[None, :], w)
+        feat, thr, leaf, assign = jax.vmap(one)(w)
     return ForestParams(feat, thr, leaf), assign
 
 

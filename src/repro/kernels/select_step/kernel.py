@@ -262,6 +262,7 @@ def select_step_call(feat, thr, leaf, y, obs, beta, bf, points, u, t_max,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="select_step",
     )(*operands)
     # Drop the state padding and the per-state columns' unit lane axis.
     return tuple(o[:s_dim, 0] if tail == (1,) else o[:s_dim]

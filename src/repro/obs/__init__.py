@@ -11,9 +11,14 @@ gate tripped.  This package is that substrate:
   segment dispatch, emitted by ``service/broker.py`` + ``service/
   engine.py`` behind ``ServiceConfig.trace``
 * ``spans``     — :func:`phase_span`: per-phase timing around the segment
-  loop (seat/inject/dispatch/device_block/harvest) with compile-vs-execute
-  attribution via ``episode_cache_size()``/``selector_cache_size()`` and
-  optional ``jax.profiler`` named scopes (``ServiceConfig.trace_profiler``)
+  loop (stage/seat/inject/dispatch/device_block/harvest, outcome inside
+  harvest) with compile-vs-execute attribution via
+  ``episode_cache_size()``/``selector_cache_size()`` and optional
+  ``jax.profiler`` annotations (``ServiceConfig.trace_profiler``)
+* ``scopes``    — :data:`SCOPES`: the device-scope vocabulary the segment
+  program's operations carry (``jax.named_scope``: fits and their parts,
+  the kernel, the lookahead), and :func:`segment_program_text`, the
+  compiled program's HLO text to join a device trace to them
 * ``export``    — Prometheus text renderer, JSONL trace writer/reader, and
   the trace validators (schema + per-ticket lifecycle state machine)
 * ``forensics`` — :func:`dump_divergence`: one JSON artifact per parity
@@ -22,10 +27,11 @@ gate tripped.  This package is that substrate:
 
 Zero-perturbation rule (docs/ARCHITECTURE.md "Observability"): this layer
 watches the determinism contract, it never joins it.  Nothing here touches
-a traced program, a PRNG key, or an Outcome; a trace-on service replays
-the trace-off service bit for bit (``tests/test_obs.py``) at <= 5%
-steps/sec cost (the obs-overhead gate in
-``benchmarks/streaming_throughput.py``).
+a PRNG key or an Outcome, and the scopes only name a traced program's
+operations; a trace-on service replays the trace-off service bit for bit
+(``tests/test_obs.py``).  Traced on the chip (TPU v5e, the benchmark's
+``tf384.lone`` cell) a window took 0.2% more per decision (285.96 against
+285.38 ms) than an untraced one on the same seed.
 """
 
 from repro.obs.export import (COUNTER_FIELDS, metrics_to_prometheus,
@@ -36,12 +42,15 @@ from repro.obs.forensics import (PINNED_OUTCOME_FIELDS, diff_outcomes,
                                  registry_signatures)
 from repro.obs.recorder import (EVENT_KINDS, TERMINAL_KINDS, Event,
                                 FlightRecorder)
+from repro.obs.scopes import (SCOPES, scope, segment_program,
+                               segment_program_text)
 from repro.obs.spans import PHASES, phase_span
 
 __all__ = [
     "COUNTER_FIELDS", "EVENT_KINDS", "Event", "FlightRecorder", "PHASES",
-    "PINNED_OUTCOME_FIELDS", "TERMINAL_KINDS", "diff_outcomes",
+    "PINNED_OUTCOME_FIELDS", "SCOPES", "TERMINAL_KINDS", "diff_outcomes",
     "dump_divergence", "metrics_to_prometheus", "outcome_to_dict",
-    "phase_span", "read_trace_jsonl", "registry_signatures",
-    "validate_lifecycle", "validate_trace", "write_trace_jsonl",
+    "phase_span", "read_trace_jsonl", "registry_signatures", "scope",
+    "segment_program", "segment_program_text", "validate_lifecycle",
+    "validate_trace", "write_trace_jsonl",
 ]

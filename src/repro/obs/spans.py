@@ -1,18 +1,20 @@
 """Per-phase timing spans around the segment loop, with compile attribution.
 
-:func:`phase_span` wraps one phase of the seat -> inject -> dispatch ->
-device_block -> harvest cycle (``service/engine.py``) and emits a ``span``
-event carrying the phase name and wall duration.  With ``compiles=True``
-the span also records how many episode/selector programs were compiled
-inside it — read off the existing ``episode_cache_size()`` /
-``selector_cache_size()`` observables — so a slow dispatch is attributable
-to *compilation* vs *execution* without a profiler.
+:func:`phase_span` wraps one phase of the stage -> seat -> inject ->
+dispatch -> device_block -> harvest cycle (``service/broker.py``,
+``service/engine.py``; ``outcome`` inside harvest, once per rebuilt
+Outcome) and emits a ``span`` event carrying the phase name and wall
+duration.  With ``compiles=True`` the span also records how many
+episode/selector programs were compiled inside it — read off the existing
+``episode_cache_size()`` / ``selector_cache_size()`` observables — so a
+slow dispatch is attributable to *compilation* vs *execution* without a
+profiler.
 
 With ``profiler=True`` the phase additionally runs under a
 ``jax.profiler.TraceAnnotation`` named scope (``ServiceConfig.
 trace_profiler``), so the phases show up by name in a captured device
-trace.  The annotation is host-side naming only: like everything in
-``repro.obs`` it cannot perturb a traced program.
+trace.  The annotation is host-side naming only; the device's work inside
+a segment is named by the scopes of ``scopes.py``.
 """
 
 from __future__ import annotations
@@ -23,16 +25,20 @@ import time
 __all__ = ["PHASES", "phase_span"]
 
 # The segment-cycle phase vocabulary, in execution order (the span diagram
-# in docs/ARCHITECTURE.md).  "dispatch" covers tracing + compilation + the
-# async enqueue of the jitted segment; "device_block" is the wait for the
-# device to finish it — their split is what separates host overhead from
-# device work.
-PHASES = ("seat", "inject", "dispatch", "device_block", "harvest")
+# in docs/ARCHITECTURE.md).  "stage" is the broker's per-shard staging
+# (backlog purge, admission, eviction picks) before the engine's cycle;
+# "dispatch" covers tracing + compilation + the async enqueue of the jitted
+# segment; "device_block" is the wait for the device to finish it — their
+# split is what separates host overhead from device work.  "outcome" times
+# one finished run's Outcome rebuild, inside "harvest".
+PHASES = ("stage", "seat", "inject", "dispatch", "device_block", "harvest",
+          "outcome")
 
 
 def _cache_sizes() -> tuple[int, int]:
     # Lazy import: obs must stay importable without pulling the whole core
-    # (and core never imports obs, so there is no cycle either way).
+    # (core imports only obs.scopes, whose module imports nothing of the
+    # repo, so there is no cycle either way).
     from repro.core import episode_cache_size, selector_cache_size
     return episode_cache_size(), selector_cache_size()
 

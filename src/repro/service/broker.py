@@ -36,7 +36,7 @@ import time
 
 from repro.core.optimizer import Outcome, RunRequest
 from repro.jobs.tables import JobTable
-from repro.obs import FlightRecorder
+from repro.obs import FlightRecorder, phase_span
 from repro.service import placement
 from repro.service.config import ServiceConfig
 from repro.service.engine import (SegmentEngine, SegmentReport,
@@ -571,31 +571,11 @@ class StreamingTuner:
             for d in range(self.num_shards):
                 adm = self._admissions[d]
                 eng = self._engines.shards[d]
-                for t in adm.purge_cancelled():
-                    self._finish_cancel(t)
-                depth = len(adm)              # admitted, not yet staged
-                with self._cond:              # atomic for _place_shard
-                    staged = adm.stage(
-                        eng.c_dim + self.config.lane_slots - eng.in_flight(),
-                        aging_rate=self.config.aging_rate)
-                    self._handed[d] = staged
-                for t in staged:
-                    self.recorder.emit("stage", ticket=t.id,
-                                       priority=t.priority, shard=d)
-                # Boundary evictions: tombstoned seats always; plus at most
-                # one preemption per shard when its own backlog is past the
-                # high-water mark.
-                evict = [t for t in eng._slot_tickets
-                         if t is not None and t._cancel_requested]
-                victim = self._preemption_victim(eng, evict, staged, depth)
-                if victim is not None:
-                    evict.append(victim)
-                # Early-exit at the low-water mark only pays off if there
-                # is backlog left to inject afterwards; otherwise run the
-                # segment to its quota (or to drained).
-                low = (self.config.resolved_low_water()
-                       if len(adm) else 0)
-                plans.append((d, eng, adm, staged, evict, low, depth))
+                with phase_span(self.recorder, "stage",
+                                segment=eng._segment_seq,
+                                profiler=self.config.trace_profiler,
+                                shard=d):
+                    plans.append(self._stage_shard(d, eng, adm))
             results = self._run_segments(plans)
             reps, resolved_tickets, failure = [], [], None
             for (d, eng, adm, staged, evict, low, depth), res in \
@@ -669,6 +649,38 @@ class StreamingTuner:
             if failure is not None:
                 raise failure
             return _merge_reports(reps, self.config.lane_slots)
+
+    def _stage_shard(self, d: int, eng: SegmentEngine,
+                     adm: _AdmissionBuffer) -> tuple:
+        """Stage shard ``d``'s next segment under the pump lock: resolve
+        its tombstoned backlog, take up to a device queue plus free seats
+        of admitted tickets, and pick the seats to evict at the boundary.
+        Returns the shard's plan ``(d, engine, admission buffer, staged,
+        evict, low water, backlog depth)``."""
+        for t in adm.purge_cancelled():
+            self._finish_cancel(t)
+        depth = len(adm)              # admitted, not yet staged
+        with self._cond:              # atomic for _place_shard
+            staged = adm.stage(
+                eng.c_dim + self.config.lane_slots - eng.in_flight(),
+                aging_rate=self.config.aging_rate)
+            self._handed[d] = staged
+        for t in staged:
+            self.recorder.emit("stage", ticket=t.id,
+                               priority=t.priority, shard=d)
+        # Boundary evictions: tombstoned seats always; plus at most one
+        # preemption per shard when its own backlog is past the high-water
+        # mark.
+        evict = [t for t in eng._slot_tickets
+                 if t is not None and t._cancel_requested]
+        victim = self._preemption_victim(eng, evict, staged, depth)
+        if victim is not None:
+            evict.append(victim)
+        # Early-exit at the low-water mark only pays off if there is
+        # backlog left to inject afterwards; otherwise run the segment to
+        # its quota (or to drained).
+        low = self.config.resolved_low_water() if len(adm) else 0
+        return d, eng, adm, staged, evict, low, depth
 
     def _run_segments(self, plans) -> list:
         """Execute the busy shards' segments; returns one slot per plan —

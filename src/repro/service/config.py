@@ -82,8 +82,8 @@ class ServiceConfig:
     """Flight recorder on/off (``repro.obs.FlightRecorder``): record every
     lifecycle transition and segment dispatch plus per-phase timing spans.
     Observability only — it cannot change a run's Outcome (the
-    zero-perturbation rule, docs/ARCHITECTURE.md "Observability"; the
-    obs-overhead benchmark gate pins the cost at <= 5% steps/sec)."""
+    zero-perturbation rule, docs/ARCHITECTURE.md "Observability", which
+    also gives its cost measured on the chip)."""
 
     trace_capacity: int = 4096
     """Flight-recorder ring size: the most recent events kept for
@@ -92,10 +92,10 @@ class ServiceConfig:
     survive ring eviction."""
 
     trace_profiler: bool = False
-    """Additionally wrap each segment phase (seat/inject/dispatch/
-    device_block/harvest) in a ``jax.profiler.TraceAnnotation`` named
-    scope, so captured device traces show the phases by name.  Requires
-    ``trace=True``."""
+    """Additionally wrap each segment phase (``repro.obs.PHASES``:
+    stage/seat/inject/dispatch/device_block/harvest/outcome) in a
+    ``jax.profiler.TraceAnnotation`` (``lynceus/<phase>``), so captured
+    device traces show the phases by name.  Requires ``trace=True``."""
 
     num_shards: int = 1
     """Resident engines the service runs, one per shard — each with its

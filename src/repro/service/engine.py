@@ -435,21 +435,24 @@ class SegmentEngine:
                                     np.float32(t.rows["beta"][0]), sel_s)
 
     def _outcome_from_row(self, t, report, r: int, sel_s: float) -> Outcome:
-        n = int(report["out_nexp"][r])
-        explored = [int(i) for i in np.asarray(report["out_expl"][r, :n])]
-        if self.settings.timeout:
-            cflags = [bool(f)
-                      for f in np.asarray(report["out_cexpl"][r, :n])]
-            billed = np.asarray(report["out_bexpl"][r, :n])
-        else:
-            cflags = [False] * len(explored)
-            billed = t.request.job.host_view().cost[explored]
-        # beta stays an np.float32 scalar: _reconstruct_outcome's
-        # ``budget - beta_final`` must run under the same f32 promotion the
-        # sequential oracle's bookkeeping uses.
-        return _reconstruct_outcome(t.request.job, self.settings, t.budget,
-                                    explored, cflags, billed,
-                                    report["out_beta"][r], sel_s)
+        with phase_span(self._recorder, "outcome", segment=self._segment_seq,
+                        profiler=self._profiler, shard=self.shard_id):
+            n = int(report["out_nexp"][r])
+            explored = [int(i)
+                        for i in np.asarray(report["out_expl"][r, :n])]
+            if self.settings.timeout:
+                cflags = [bool(f)
+                          for f in np.asarray(report["out_cexpl"][r, :n])]
+                billed = np.asarray(report["out_bexpl"][r, :n])
+            else:
+                cflags = [False] * len(explored)
+                billed = t.request.job.host_view().cost[explored]
+            # beta stays an np.float32 scalar: _reconstruct_outcome's
+            # ``budget - beta_final`` must run under the same f32 promotion
+            # the sequential oracle's bookkeeping uses.
+            return _reconstruct_outcome(t.request.job, self.settings,
+                                        t.budget, explored, cflags, billed,
+                                        report["out_beta"][r], sel_s)
 
 
 class ShardedEngine:

@@ -130,8 +130,8 @@ def test_phase_span_rejects_unknown_phase_and_skips_disabled():
 
 
 def test_phase_vocabulary_matches_cycle_order():
-    assert PHASES == ("seat", "inject", "dispatch", "device_block",
-                      "harvest")
+    assert PHASES == ("stage", "seat", "inject", "dispatch", "device_block",
+                      "harvest", "outcome")
 
 
 # --------------------------------------------------------------------------- #
@@ -353,3 +353,61 @@ def test_obs_report_renders_a_real_trace(tmp_path, capsys):
         assert obs_report.main() == 1
     finally:
         sys.argv = argv
+
+
+# --------------------------------------------------------------------------- #
+# Device scopes: op metadata only
+# --------------------------------------------------------------------------- #
+def _scopes_off(monkeypatch):
+    """Trace every program as if no scope were applied (fresh caches, so
+    nothing traced with the scopes is reused)."""
+    import contextlib
+
+    import jax
+
+    from repro.core import lookahead, optimizer, trees
+    for mod in (lookahead, optimizer, trees):
+        monkeypatch.setattr(mod, "scope",
+                            lambda name: contextlib.nullcontext())
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("program", ["episode/segment",
+                                     "episode/segment/bucketed",
+                                     "selector/lynceus/native/fused",
+                                     "selector/lynceus/native/timeout"])
+def test_scopes_leave_the_program_signature_unchanged(program, monkeypatch):
+    """A named scope adds no jaxpr equation: the ``repro.analysis``
+    signature of each audited program is the same with and without
+    them."""
+    import jax
+
+    from repro.analysis import registered_programs, signature
+    spec = next(p for p in registered_programs() if p.name == program)
+    jax.clear_caches()
+    fn, example, _ = spec.build()
+    scoped = signature(fn, *example)
+    _scopes_off(monkeypatch)
+    fn, example, _ = spec.build()
+    assert signature(fn, *example) == scoped
+
+
+def test_scopes_leave_served_outcomes_bit_identical(monkeypatch):
+    """The same queue served with and without the scopes gives the same
+    outcomes, ``spend_trajectory`` included (exact refit, so every fit
+    scope is on the path)."""
+    s = Settings(policy="lynceus", la=1, k_gh=2, n_trees=3, depth=2,
+                 refit="exact", timeout=True)
+    scoped = run_queue(_REQS[:3], s)
+    _scopes_off(monkeypatch)
+    _assert_outcomes_equal(scoped, run_queue(_REQS[:3], s),
+                           tag="scopes_on_vs_off")
+
+
+def test_scope_vocabulary():
+    from repro.obs import SCOPES, scope
+    assert SCOPES == ("lynceus", "segment", "speculate", "fit",
+                      "fit/bootstrap", "fit/split_stats", "fit/split_choice",
+                      "fit/route", "select_step")
+    with pytest.raises(ValueError, match="unknown scope"):
+        scope("fit/warp")
