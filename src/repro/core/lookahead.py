@@ -50,9 +50,9 @@ from repro.kernels.dispatch import resolve_mode as _resolve_kernel_mode
 from repro.kernels.select_step.kernel import select_step_call
 from repro.obs.scopes import scope
 
-__all__ = ["Settings", "select_next", "select_next_batched", "make_selector",
-           "make_batch_selector", "space_arrays", "space_valid",
-           "slot_price_rows", "selector_cache_size"]
+__all__ = ["Settings", "select_next", "select_next_batched", "draw_passes",
+           "make_selector", "make_batch_selector", "space_arrays",
+           "space_valid", "slot_price_rows", "selector_cache_size"]
 
 _EPS = 1e-9
 
@@ -132,14 +132,14 @@ def _sigma_floor(y, obs_mask, rel):
 
 
 def _fit_root(key, y, obs_mask, cens, points, left, thresholds, floor,
-              s: Settings):
+              s: Settings, n_chunks=None):
     """Root ensemble fit.  Censored points (``cens`` not None) enter the fit
     as regular observations at their billed lower bound — they shape split
     structure — and the resulting posterior is corrected at those configs
     (mean clamped to the bound, sigma inflated; see acq.censored_adjust)."""
     params, assign = trees.fit_forest(
         key, y, obs_mask, points, left, thresholds,
-        n_trees=s.n_trees, depth=s.depth)
+        n_trees=s.n_trees, depth=s.depth, n_chunks=n_chunks)
     preds = jnp.take_along_axis(params.leaf, assign, axis=1)   # [B, M]
     mu, sigma = trees.forest_mu_sigma(preds, floor)
     if cens is not None:
@@ -165,7 +165,7 @@ def _map_states(fn, keys, y_b, m_b):
 
 
 def _fit_batch_exact(key, y_b, m_b, cens_b, points, left, thresholds, floor,
-                     s: Settings):
+                     s: Settings, n_chunks=None):
     """y_b, m_b[, cens_b]: [S, M] -> mu, sigma: [S, M].
 
     Per-state keys derive from ``fold_in(key, state_index)`` rather than
@@ -181,7 +181,8 @@ def _fit_batch_exact(key, y_b, m_b, cens_b, points, left, thresholds, floor,
 
     def one(k, y, m):
         p, a = trees.fit_forest(k, y, m, points, left, thresholds,
-                                n_trees=s.n_trees, depth=s.depth)
+                                n_trees=s.n_trees, depth=s.depth,
+                                n_chunks=n_chunks)
         preds = jnp.take_along_axis(p.leaf, a, axis=1)
         return trees.forest_mu_sigma(preds, floor)
 
@@ -192,7 +193,8 @@ def _fit_batch_exact(key, y_b, m_b, cens_b, points, left, thresholds, floor,
     return mu, sigma
 
 
-def _fit_batch_params(key, y_b, m_b, points, left, thresholds, s: Settings):
+def _fit_batch_params(key, y_b, m_b, points, left, thresholds, s: Settings,
+                      n_chunks=None):
     """Per-state forest *parameters* [S, B, D, W] for the fused kernel.
 
     The same ``fold_in(key, state_index)`` key schedule as
@@ -205,7 +207,8 @@ def _fit_batch_params(key, y_b, m_b, points, left, thresholds, s: Settings):
 
     def one(k, y, m):
         p, _ = trees.fit_forest(k, y, m, points, left, thresholds,
-                                n_trees=s.n_trees, depth=s.depth)
+                                n_trees=s.n_trees, depth=s.depth,
+                                n_chunks=n_chunks)
         return p
 
     return _map_states(one, keys, y_b, m_b)
@@ -254,7 +257,7 @@ def _ystar(best_feas, y_b, m_b, sigma, valid=None):
 # --------------------------------------------------------------------------- #
 def _recurse(key, y_b, m_b, beta_b, bf_b, depth_left, *, points, left,
              thresholds, u, t_max, floor, s: Settings, frozen_ctx,
-             cens_b=None, valid=None):
+             cens_b=None, valid=None, n_chunks=None):
     """Score each state's own argmax-EI_c pick; branch if depth_left > 0.
 
     Returns (reward [S], cost [S]) — already zeroed for states whose Gamma is
@@ -262,7 +265,8 @@ def _recurse(key, y_b, m_b, beta_b, bf_b, depth_left, *, points, left,
     parent's censored observations; speculation only ever adds fully-observed
     points, so the mask is constant down the path.  ``valid`` ([M] or None)
     is the run's point-validity mask (padded selector programs): padding
-    lanes are never candidates, at any speculation depth.
+    lanes are never candidates, at any speculation depth.  ``n_chunks``
+    is the bootstrap draw's pass count (``trees.fit_forest``).
     """
     k_fit, k_next = jax.random.split(key)
     fused = _fused_mode(s)
@@ -273,7 +277,7 @@ def _recurse(key, y_b, m_b, beta_b, bf_b, depth_left, *, points, left,
         # the [S, M] sweep (ensemble descent -> censored adjust -> EI_c ->
         # Gamma -> quantized argmax) runs in one Pallas program.
         params = _fit_batch_params(k_fit, y_b, m_b, points, left,
-                                   thresholds, s)
+                                   thresholds, s, n_chunks)
         with scope("select_step"):
             out = select_step_call(
                 params.feat, params.thr, params.leaf, y_b, m_b, beta_b,
@@ -296,7 +300,8 @@ def _recurse(key, y_b, m_b, beta_b, bf_b, depth_left, *, points, left,
                                                 s.cens_sigma_rel)
         else:
             mu, sigma = _fit_batch_exact(k_fit, y_b, m_b, cens_b, points,
-                                         left, thresholds, floor, s)
+                                         left, thresholds, floor, s,
+                                         n_chunks)
         ystar = _ystar(bf_b, y_b, m_b, sigma, valid)
         eic = acq.ei_constrained(mu, sigma, ystar[:, None], u[None, :],
                                  t_max)
@@ -341,7 +346,8 @@ def _recurse(key, y_b, m_b, beta_b, bf_b, depth_left, *, points, left,
         k_next, flat(y_child), flat(m_child), flat(beta_child),
         flat(bf_child), depth_left - 1, points=points, left=left,
         thresholds=thresholds, u=u, t_max=t_max, floor=floor, s=s,
-        frozen_ctx=child_frozen, cens_b=cens_child, valid=valid)
+        frozen_ctx=child_frozen, cens_b=cens_child, valid=valid,
+        n_chunks=n_chunks)
     r_ch = r_ch.reshape(s_dim, s.k_gh)
     c_ch = c_ch.reshape(s_dim, s.k_gh)
     # G-H expectation via the pinned fenced dot (acq.gh_expect): a raw `@ w`
@@ -355,7 +361,8 @@ def _recurse(key, y_b, m_b, beta_b, bf_b, depth_left, *, points, left,
 
 
 def _select_next_fused(key, y, obs_mask, beta, points, left, thresholds, u,
-                       t_max, s: Settings, cens, valid, mode: str):
+                       t_max, s: Settings, cens, valid, mode: str,
+                       n_chunks=None):
     """Fused-root twin of :func:`_select_next_impl` (same contract).
 
     The root forest fit keeps the unfused key schedule (``k_root`` feeds
@@ -371,7 +378,7 @@ def _select_next_fused(key, y, obs_mask, beta, points, left, thresholds, u,
     k_root, k_path = jax.random.split(key)
     params, _ = trees.fit_forest(k_root, y, obs_mask, points, left,
                                  thresholds, n_trees=s.n_trees,
-                                 depth=s.depth)
+                                 depth=s.depth, n_chunks=n_chunks)
 
     obs = obs_mask.astype(bool)
     feas_obs = obs & (y <= t_max * u)
@@ -441,7 +448,8 @@ def _select_next_fused(key, y, obs_mask, beta, points, left, thresholds, u,
     r1, c1 = _recurse(
         k_path, flat(y1), flat(m1), flat(beta1), flat(bf1), s.la - 1,
         points=points, left=left, thresholds=thresholds, u=u, t_max=t_max,
-        floor=floor, s=s, frozen_ctx=None, cens_b=cens1, valid=valid)
+        floor=floor, s=s, frozen_ctx=None, cens_b=cens1, valid=valid,
+        n_chunks=n_chunks)
     reward = reward + acq.no_contract(
         s.gamma * acq.gh_expect(r1.reshape(m_dim, s.k_gh), w))
     cost = cost + acq.gh_expect(c1.reshape(m_dim, s.k_gh), w)
@@ -453,7 +461,8 @@ def _select_next_fused(key, y, obs_mask, beta, points, left, thresholds, u,
 
 
 def _select_next_impl(key, y, obs_mask, beta, points, left, thresholds, u,
-                      t_max, s: Settings, cens=None, valid=None):
+                      t_max, s: Settings, cens=None, valid=None,
+                      n_chunks=None):
     """One NextConfig step. Returns (index, valid, diagnostics).
 
     y: [M] observed costs (value irrelevant where unobserved);
@@ -463,7 +472,9 @@ def _select_next_impl(key, y, obs_mask, beta, points, left, thresholds, u,
     valid: [M] point-validity mask or None — False marks right-padding
     lanes of a geometry-bucketed space (``space.pad_to``).  Padding can
     never be untested-candidate, incumbent fallback, or Gamma member; with
-    valid None the traced program is unchanged from the unpadded selector.
+    valid None the traced program is unchanged from the unpadded selector;
+    n_chunks: None, or the bootstrap draw's pass count for every fit of
+    the step (``trees.fit_forest``), from :func:`draw_passes`.
 
     With ``s.timeout`` the diagnostics carry ``"timeout"``: the predictive
     cap τ (runtime units) the driver must abort the selected exploration at.
@@ -476,12 +487,13 @@ def _select_next_impl(key, y, obs_mask, beta, points, left, thresholds, u,
     if fused is not None:
         return _select_next_fused(key, y, obs_mask, beta, points, left,
                                   thresholds, u, t_max, s, cens, valid,
-                                  fused)
+                                  fused, n_chunks)
     m_dim = y.shape[0]
     floor = _sigma_floor(y, obs_mask, s.sigma_floor_rel)
     k_root, k_path = jax.random.split(key)
     params, assign, preds, mu0, sig0 = _fit_root(
-        k_root, y, obs_mask, cens, points, left, thresholds, floor, s)
+        k_root, y, obs_mask, cens, points, left, thresholds, floor, s,
+        n_chunks)
 
     obs = obs_mask.astype(bool)
     feas_obs = obs & (y <= t_max * u)
@@ -566,7 +578,7 @@ def _select_next_impl(key, y, obs_mask, beta, points, left, thresholds, u,
         k_path, flat(y1), flat(m1), flat(beta1), flat(bf1), s.la - 1,
         points=points, left=left, thresholds=thresholds, u=u, t_max=t_max,
         floor=floor, s=s, frozen_ctx=frozen_ctx, cens_b=cens1,
-        valid=valid)
+        valid=valid, n_chunks=n_chunks)
     reward = reward + acq.no_contract(
         s.gamma * acq.gh_expect(r1.reshape(m_dim, s.k_gh), w))
     cost = cost + acq.gh_expect(c1.reshape(m_dim, s.k_gh), w)
@@ -582,7 +594,8 @@ select_next = jax.jit(_select_next_impl, static_argnames=("s",))
 
 @functools.partial(jax.jit, static_argnames=("s",))
 def select_next_batched(keys, y, obs_mask, beta, points, left, thresholds, u,
-                        t_max, s: Settings, cens=None, valid=None):
+                        t_max, s: Settings, cens=None, valid=None,
+                        n_chunks=None):
     """NextConfig for R independent slots at once (the batched-harness entry).
 
     keys: [R, 2] PRNG keys; y: [R, M]; obs_mask: [R, M]; beta: [R];
@@ -603,6 +616,12 @@ def select_next_batched(keys, y, obs_mask, beta, points, left, thresholds, u,
     different native geometries padded into one bucket; ``valid`` is then
     the per-slot ([R, M]) or shared ([M]) point-validity mask of the
     padding (None for unpadded spaces: the traced program is unchanged).
+
+    ``n_chunks`` (a traced scalar, shared by every slot, or None for the
+    full-width draw) is the bootstrap draw's pass count
+    (``trees.fit_forest``), from :func:`draw_passes`.  Shared and
+    unbatched, it keeps the draw a loop whose trip count is one for all
+    slots.
     """
     per_slot_u = jnp.ndim(u) == 2
     per_slot_t = jnp.ndim(t_max) == 1
@@ -617,7 +636,7 @@ def select_next_batched(keys, y, obs_mask, beta, points, left, thresholds, u,
     def one(k, y_r, m_r, b_r, c_r, u_r, t_r, p_r, l_r, th_r, v_r):
         with scope("speculate"):
             return _select_next_impl(k, y_r, m_r, b_r, p_r, l_r, th_r,
-                                     u_r, t_r, s, c_r, v_r)
+                                     u_r, t_r, s, c_r, v_r, n_chunks)
 
     sp_ax = 0 if per_slot_space else None
     return jax.vmap(one, in_axes=(0, 0, 0, 0,
@@ -630,6 +649,18 @@ def select_next_batched(keys, y, obs_mask, beta, points, left, thresholds, u,
         keys, y, obs_mask, beta, cens, u, t_max, points, left, thresholds,
         valid)
 
+
+def draw_passes(obs_mask, active, s: Settings):
+    """The bootstrap draw's pass count (``trees.draw_chunks``) for one
+    :func:`select_next_batched` call: enough for every fit whose pick is
+    used.  A fit holds its slot's observed points plus at most ``s.la``
+    that speculation adds (each level of :func:`_recurse` adds one), so
+    the count covers the largest observed count among the ``active``
+    slots plus ``s.la``.  A seat left idle keeps the mask of the run it
+    last held and its pick is discarded, so it is left out: it must not
+    widen every slot's draw.  obs_mask: [R, M]; active: [R] bool."""
+    n_obs = jnp.where(active, obs_mask.sum(axis=1, dtype=jnp.int32), 0)
+    return trees.draw_chunks(jnp.max(n_obs) + s.la, obs_mask.shape[1])
 
 def slot_price_rows(job_ids, rid, u, t_max):
     """Resolve each lane slot's price row and SLO for slot-indexed selection.

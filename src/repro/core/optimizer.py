@@ -738,12 +738,14 @@ def _episode_segment(carry, queue, qtail, evict, low_water, step_quota,
     Returns ``(carry', report)``: the updated persistent slot state and the
     per-segment report (``out_done``/``out_beta``/``out_nexp``/``out_expl``
     [+ ``out_cexpl``/``out_bexpl`` with timeouts] banking buffers, plus
-    ``steps`` and ``busy`` — active-slot-steps, the lane-occupancy
-    numerator).  Seating order is deterministic (queue order by slot index)
-    but — because every run's PRNG chain, budget arithmetic and decision
-    pipeline are functions of its own state only — outcomes are independent
-    of seating *and* arrival order; the caller re-keys results by run id,
-    never by slot.
+    ``steps``, ``busy`` — active-slot-steps, the lane-occupancy
+    numerator — and ``boot_draw_points``, the points each step's
+    bootstrap draw covered (``lookahead.draw_passes`` passes of
+    ``trees.draw_chunk(M)``), summed over steps).  Seating order is
+    deterministic (queue order by slot index) but — because every run's
+    PRNG chain, budget arithmetic and decision pipeline are functions of
+    its own state only — outcomes are independent of seating *and* arrival
+    order; the caller re-keys results by run id, never by slot.
     """
     with scope("lynceus"), scope("segment"):
         l_dim, m_dim = carry["y"].shape
@@ -770,10 +772,11 @@ def _episode_segment(carry, queue, qtail, evict, low_water, step_quota,
                 val_l = valid[jid]
             else:
                 pts_l, left_l, thr_l, val_l = points, left, thresholds, valid
+            n_chunks = lookahead.draw_passes(st["mask"], st["active"], s)
             idx, sel_ok, diag = lookahead.select_next_batched(
                 sub, st["y"], st["mask"], jnp.maximum(st["beta"], 0.0),
                 pts_l, left_l, thr_l, u_l, t_l, s,
-                st["cens"] if s.timeout else None, val_l)
+                st["cens"] if s.timeout else None, val_l, n_chunks)
             if jid is None:
                 c = cost[idx]
                 t_run = runtime[idx] if s.timeout else None
@@ -820,7 +823,9 @@ def _episode_segment(carry, queue, qtail, evict, low_water, step_quota,
                    "active": alive | got,
                    "qhead": st["qhead"] + got.sum(dtype=jnp.int32),
                    "steps": st["steps"] + 1,
-                   "busy": st["busy"] + st["active"].sum(dtype=jnp.int32)}
+                   "busy": st["busy"] + st["active"].sum(dtype=jnp.int32),
+                   "boot_draw_points": (st["boot_draw_points"]
+                                        + n_chunks * trees.draw_chunk(m_dim))}
             for k, v in step.items():
                 nxt[k] = fill(queue[k], v)
             nxt.update(out)
@@ -828,6 +833,7 @@ def _episode_segment(carry, queue, qtail, evict, low_water, step_quota,
 
         st0 = dict(carry)
         st0.update(steps=jnp.int32(0), busy=jnp.int32(0),
+                   boot_draw_points=jnp.int32(0),
                    out_done=jnp.zeros((n_out,), bool),
                    out_beta=jnp.zeros((n_out,), jnp.float32),
                    out_nexp=jnp.zeros((n_out,), jnp.int32),
@@ -857,7 +863,8 @@ def _episode_segment(carry, queue, qtail, evict, low_water, step_quota,
 
         st = jax.lax.while_loop(cond, body, st0)
         report = {k: st.pop(k) for k in list(st)
-                  if k.startswith("out_") or k in ("steps", "busy")}
+                  if k.startswith("out_")
+                  or k in ("steps", "busy", "boot_draw_points")}
         return st, report
 
 

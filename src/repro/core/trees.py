@@ -41,7 +41,8 @@ from repro.core.acquisition import quantize_scores as _quantize_scores
 from repro.obs.scopes import scope
 
 __all__ = [
-    "ForestParams", "bootstrap_weights", "make_left_table", "fit_forest",
+    "ForestParams", "bootstrap_weights", "draw_chunk", "draw_chunks",
+    "observed_bootstrap_weights", "make_left_table", "fit_forest",
     "predict_forest", "forest_mu_sigma", "fit_predict_mu_sigma",
 ]
 
@@ -83,6 +84,22 @@ def _pinned_sum0(x: jax.Array, axis: int = 0) -> jax.Array:
 # the whole draw free of data-dependent control flow.
 _BOOT_ITERS = 24
 _KNUTH_L = np.float32(np.exp(-1.0))
+# Points per pass of :func:`observed_bootstrap_weights`.  A pass draws all
+# of its points whether or not they are observed, so a smaller pass wastes
+# less past the last observed point and a larger one runs fewer passes.
+_DRAW_CHUNK = 64
+
+
+def _poisson_counts(key: jax.Array, n_trees: int, idx: jax.Array
+                    ) -> jax.Array:
+    """Poisson(1) counts ``[len(idx), n_trees]`` (int32) of points ``idx``:
+    point i's under its own ``fold_in(key, i)`` subkey, from a fixed-
+    iteration Knuth sampler (count the uniforms whose running product stays
+    above e^-1)."""
+    point_keys = jax.vmap(jax.random.fold_in, (None, 0))(key, idx)
+    u = jax.vmap(lambda k: jax.random.uniform(k, (_BOOT_ITERS, n_trees)))(
+        point_keys)                                        # [n, I, B]
+    return (jnp.cumprod(u, axis=1) > _KNUTH_L).sum(axis=1)  # [n, B]
 
 
 def bootstrap_weights(key: jax.Array, n_trees: int, m: int) -> jax.Array:
@@ -90,24 +107,77 @@ def bootstrap_weights(key: jax.Array, n_trees: int, m: int) -> jax.Array:
 
     Weight (b, i) is a pure function of ``(key, b, i)`` and never of ``m``:
     each point derives its own ``fold_in(key, i)`` subkey and runs a
-    fixed-iteration Knuth sampler (count the uniforms whose running product
-    stays above e^-1) on uniforms drawn under that subkey alone.  Right-
-    padding a space to a geometry bucket therefore replays the native
-    points' draws bit-for-bit — the property the padded selector programs
-    in ``core/lookahead.py`` rely on.  A raw ``jax.random.poisson(key,
-    (B, M))`` draw does NOT have it: threefry pairs counter blocks by the
-    total element count, so every weight shifts whenever M changes.
+    fixed-iteration Knuth sampler on uniforms drawn under that subkey
+    alone.  Right-padding a space to a geometry bucket therefore replays
+    the native points' draws bit-for-bit — the property the padded
+    selector programs in ``core/lookahead.py`` rely on.  A raw
+    ``jax.random.poisson(key, (B, M))`` draw does NOT have it: threefry
+    pairs counter blocks by the total element count, so every weight
+    shifts whenever M changes.  The same property lets
+    :func:`observed_bootstrap_weights` draw only the observed points and
+    give the same bits where a fit uses them: how many points the served
+    segment draws follows its active lanes' observed count, the bits do
+    not (``fit_forest``'s ``n_chunks``).
 
     The running product is compared directly (exactly-rounded float32
     multiplies against a host constant) rather than through log-space sums,
     so no geometry-sensitive transcendental sits upstream of the integer
     weights.
     """
-    point_keys = jax.vmap(jax.random.fold_in, (None, 0))(key, jnp.arange(m))
-    u = jax.vmap(lambda k: jax.random.uniform(k, (_BOOT_ITERS, n_trees)))(
-        point_keys)                                        # [m, I, B]
-    k = (jnp.cumprod(u, axis=1) > _KNUTH_L).sum(axis=1)    # [m, B]
-    return k.T.astype(jnp.float32)
+    return _poisson_counts(key, n_trees, jnp.arange(m)).T.astype(jnp.float32)
+
+
+def draw_chunk(m: int) -> int:
+    """Points per pass of :func:`observed_bootstrap_weights` on an
+    ``m``-point space: ``_DRAW_CHUNK``, evened out so that the fewest
+    passes covering ``m`` overshoot it by less than one point each."""
+    passes = -(-m // _DRAW_CHUNK)
+    return -(-m // passes)
+
+
+def draw_chunks(n_points, m: int) -> jax.Array:
+    """Passes of :func:`observed_bootstrap_weights` that cover ``n_points``
+    (traced) observed points of an ``m``-point space, at most the passes
+    that cover all ``m``."""
+    ch = draw_chunk(m)
+    return jnp.minimum((n_points + ch - 1) // ch, -(-m // ch))
+
+
+def observed_bootstrap_weights(key: jax.Array, n_trees: int,
+                               obs_mask: jax.Array, n_chunks) -> jax.Array:
+    """``bootstrap_weights(key, n_trees, m) * obs_mask``, drawn only at the
+    observed points: the same bits, ``+0.0`` at every other point.
+
+    The observed points are taken in ascending order, :func:`draw_chunk`
+    at a pass, for ``n_chunks`` passes (:func:`draw_chunks`; a traced
+    count, so how many points are drawn follows the data without a
+    recompile).  Each point's counts come from :func:`_poisson_counts`,
+    the same program :func:`bootstrap_weights` runs, and are scattered
+    into a zero buffer; a pass's slots past the last observed point are
+    dropped.  Observed points beyond ``n_chunks`` passes are not drawn and
+    weigh 0: a caller passes enough passes for every fit whose result it
+    uses.
+    """
+    m = obs_mask.shape[0]
+    ch = draw_chunk(m)
+    obs = obs_mask.astype(bool)
+    rank = jnp.cumsum(obs.astype(jnp.int32))   # 1 + rank at observed points
+    points = jnp.arange(m)
+
+    def one_pass(c, boot):
+        # hit[j, i]: point i is observed point number c·ch + j.  A slot
+        # past the last observed point has no hit: its draw lands nowhere.
+        hit = obs[None, :] & (rank[None, :]
+                              == c * ch + jnp.arange(1, ch + 1)[:, None])
+        idx = jnp.sum(jnp.where(hit, points[None, :], 0), axis=1)
+        k = _poisson_counts(key, n_trees, idx).T.astype(jnp.float32)
+        # The scatter as a one-hot contraction: each point takes one
+        # integer count (exact in bfloat16, so at any matmul precision)
+        # and every other point a sum of +0.0 products.
+        return boot + k @ hit.astype(jnp.float32)
+
+    return jax.lax.fori_loop(0, n_chunks, one_pass,
+                             jnp.zeros((n_trees, m), jnp.float32))
 
 
 class ForestParams(NamedTuple):
@@ -252,8 +322,8 @@ def _fit_one_tree(y: jax.Array, w: jax.Array, points: jax.Array,
 
 def fit_forest(key: jax.Array, y: jax.Array, obs_mask: jax.Array,
                points: jax.Array, left: jax.Array, thresholds: jax.Array, *,
-               n_trees: int, depth: int, min_weight: float = 1.0
-               ) -> tuple[ForestParams, jax.Array]:
+               n_trees: int, depth: int, min_weight: float = 1.0,
+               n_chunks=None) -> tuple[ForestParams, jax.Array]:
     """Fit the bagged forest.
 
     Args:
@@ -263,6 +333,15 @@ def fit_forest(key: jax.Array, y: jax.Array, obs_mask: jax.Array,
       points: [M, F] normalized features of the whole space.
       left: [M, F, T] precomputed ``make_left_table``.
       thresholds: [F, T] normalized threshold values (+inf padded).
+      n_chunks: None to draw the bootstrap at all M points
+        (:func:`bootstrap_weights`), or a traced count of passes that
+        covers ``obs_mask``'s points (:func:`draw_chunks`) to draw only
+        those (:func:`observed_bootstrap_weights`).  The weights, and so
+        the fit, are the same bits either way; only the work follows the
+        observed count.  The served segment passes the count its *active*
+        lanes need (``lookahead.draw_passes``): an idle seat keeps
+        the stale mask of its last run and its pick is discarded, so it
+        is left out rather than let widen every lane's draw.
     Returns:
       (ForestParams, per_tree_leaf_assignment [B, M]) — the assignment lets
       tabular callers predict with a single gather.
@@ -292,7 +371,11 @@ def fit_forest(key: jax.Array, y: jax.Array, obs_mask: jax.Array,
     with scope("fit"):
         obs = obs_mask.astype(jnp.float32)
         with scope("fit/bootstrap"):
-            boot = bootstrap_weights(key, n_trees, m)
+            if n_chunks is None:
+                boot = bootstrap_weights(key, n_trees, m)
+            else:
+                boot = observed_bootstrap_weights(key, n_trees, obs_mask,
+                                                  n_chunks)
             w = boot * obs[None, :]
             # Guard: a tree whose bootstrap came up all-zero falls back to
             # plain obs.

@@ -29,9 +29,9 @@ __all__ = ["COUNTER_FIELDS", "metrics_to_prometheus", "read_trace_jsonl",
 # ServiceMetrics fields that are monotone counters within a metrics window
 # (everything else in the snapshot is a gauge: ratios, depths, latencies).
 COUNTER_FIELDS = frozenset({
-    "segments", "steps", "busy_slot_steps", "submitted", "resolved",
-    "cancelled", "preempted", "resumed", "slo_missed", "deadline_rejected",
-    "explorations",
+    "segments", "steps", "busy_slot_steps", "boot_draw_points",
+    "submitted", "resolved", "cancelled", "preempted", "resumed",
+    "slo_missed", "deadline_rejected", "explorations",
 })
 
 

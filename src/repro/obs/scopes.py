@@ -18,7 +18,8 @@ scope                  what it holds
                        states, G-H expectation, key splits, ratio argmax
 ``fit``                the whole of ``trees.fit_forest``, root and
                        speculative
-``fit/bootstrap``      Poisson bootstrap draws and the dead-tree guard
+``fit/bootstrap``      Poisson bootstrap draws (all points, or the
+                       observed ones) and the dead-tree guard
 ``fit/split_stats``    the ``HIGHEST`` node-statistics product per level
 ``fit/split_choice``   gains, noise floor, quantized argmax, threshold
                        select

@@ -270,6 +270,7 @@ def _merge_reports(reps: list[SegmentReport],
         evicted=sum(r.evicted for r in reps),
         resumed=sum(r.resumed for r in reps),
         dropped=sum(r.dropped for r in reps),
+        boot_draw_points=sum(r.boot_draw_points for r in reps),
     )
 
 
@@ -637,7 +638,8 @@ class StreamingTuner:
                     metrics.record_resume(rep.resumed)
                 if rep.steps:
                     metrics.record_segment(rep.steps, rep.busy_slot_steps,
-                                           rep.wall_seconds, depth)
+                                           rep.wall_seconds, depth,
+                                           rep.boot_draw_points)
                 resolved_tickets.extend(t for t, _ in resolved)
                 reps.append(rep)
             with self._cond:

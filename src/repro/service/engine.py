@@ -67,6 +67,8 @@ class SegmentReport:
     evicted: int = 0        # seats banked partial + freed at the boundary
     resumed: int = 0        # previously preempted runs re-seated on device
     dropped: int = 0        # cancel-requested staged runs filtered pre-seat
+    boot_draw_points: int = 0   # sum over iterations of the points the
+                                # forest fits' bootstrap draw covered
 
     @property
     def occupancy(self) -> float:
@@ -401,6 +403,7 @@ class SegmentEngine:
                 resumed += 1
         rep = SegmentReport(
             steps=steps, busy_slot_steps=int(report["busy"]),
+            boot_draw_points=int(report["boot_draw_points"]),
             lane_slots=self.l_dim, wall_seconds=wall, seated=seated,
             injected=len(staged_q), consumed=consumed,
             completed=len(resolved), in_flight=self.in_flight(),

@@ -34,6 +34,9 @@ class ServiceMetrics:
     steps: int               # exploration loop iterations across segments
     busy_slot_steps: int     # seated-slot iterations (occupancy numerator)
     lane_occupancy: float    # busy_slot_steps / (steps * lane_slots)
+    boot_draw_points: int    # points the fits' bootstrap draws covered,
+                             # summed over steps; over steps * M, the
+                             # share of the full-width draw
     submitted: int
     resolved: int
     cancelled: int           # tickets resolved as cancelled
@@ -94,6 +97,7 @@ class MetricsRecorder:
             self._segments = 0
             self._steps = 0
             self._busy = 0
+            self._draw_points = 0
             self._submitted = 0
             self._resolved = 0
             self._cancelled = 0
@@ -142,11 +146,13 @@ class MetricsRecorder:
             return self._latency_min
 
     def record_segment(self, steps: int, busy_slot_steps: int,
-                       wall_seconds: float, queue_depth: int) -> None:
+                       wall_seconds: float, queue_depth: int,
+                       boot_draw_points: int = 0) -> None:
         with self._lock:
             self._segments += 1
             self._steps += steps
             self._busy += busy_slot_steps
+            self._draw_points += boot_draw_points
             self._serve_seconds += wall_seconds
             self._depth_sum += queue_depth
             self._depth_max = max(self._depth_max, queue_depth)
@@ -191,6 +197,7 @@ class MetricsRecorder:
                 raw.append({
                     "slots": r._lane_slots, "segments": r._segments,
                     "steps": r._steps, "busy": r._busy,
+                    "draw_points": r._draw_points,
                     "submitted": r._submitted, "resolved": r._resolved,
                     "cancelled": r._cancelled, "preempted": r._preempted,
                     "resumed": r._resumed, "slo_missed": r._slo_missed,
@@ -222,6 +229,7 @@ class MetricsRecorder:
             steps=steps,
             busy_slot_steps=busy,
             lane_occupancy=busy / max(occ_denom, 1),
+            boot_draw_points=tot("draw_points"),
             submitted=submitted,
             resolved=resolved,
             cancelled=cancelled,
@@ -257,6 +265,7 @@ class MetricsRecorder:
                 busy_slot_steps=self._busy,
                 lane_occupancy=self._busy / max(self._steps
                                                 * self._lane_slots, 1),
+                boot_draw_points=self._draw_points,
                 submitted=self._submitted,
                 resolved=self._resolved,
                 cancelled=self._cancelled,

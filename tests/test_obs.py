@@ -207,6 +207,7 @@ def test_prometheus_rendering_types_and_values():
     text = metrics_to_prometheus(rec.snapshot())
     assert "# TYPE lynceus_service_resolved counter" in text
     assert "# TYPE lynceus_service_lane_occupancy gauge" in text
+    assert "# TYPE lynceus_service_boot_draw_points counter" in text
     assert "lynceus_service_resolved 1" in text
     assert "lynceus_service_latency_floor_s 0.5" in text
     # every line is either a TYPE annotation or "<series> <float>"

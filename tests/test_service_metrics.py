@@ -32,9 +32,10 @@ def test_single_sample_pins_percentiles_and_means():
     rec = MetricsRecorder(lane_slots=2)
     rec.record_submit()
     rec.record_segment(steps=5, busy_slot_steps=7, wall_seconds=2.0,
-                       queue_depth=3)
+                       queue_depth=3, boot_draw_points=640)
     rec.record_resolve(latency_seconds=0.25, nex=12)
     m = rec.snapshot()
+    assert m.boot_draw_points == 640
     assert m.latency_p50_s == m.latency_p95_s == m.latency_mean_s == 0.25
     assert m.outstanding == 0
     assert m.lane_occupancy == pytest.approx(7 / (5 * 2))
@@ -177,7 +178,8 @@ def _drive(rec: MetricsRecorder, rng: np.random.Generator,
             rec.record_segment(int(rng.integers(1, 9)),
                                int(rng.integers(0, 17)),
                                float(rng.uniform(0.001, 0.1)),
-                               int(rng.integers(0, 5)))
+                               int(rng.integers(0, 5)),
+                               int(rng.integers(0, 3073)))
         elif k == 4:
             rec.record_preempt()
         elif k == 5:
@@ -243,8 +245,8 @@ def test_aggregate_properties(seed, shards):
             _drive(rec, rng, int(rng.integers(0, 40)))
     per = [r.snapshot() for r in recs]
     agg = MetricsRecorder.aggregate(recs)
-    for f in ("segments", "steps", "busy_slot_steps", "submitted",
-              "resolved", "cancelled", "preempted", "resumed",
+    for f in ("segments", "steps", "busy_slot_steps", "boot_draw_points",
+              "submitted", "resolved", "cancelled", "preempted", "resumed",
               "slo_missed", "deadline_rejected", "explorations"):
         assert getattr(agg, f) == sum(getattr(m, f) for m in per), f
     assert agg.lane_slots == sum(m.lane_slots for m in per)
